@@ -367,6 +367,9 @@ def run(classifier="qat", devices=1, shard_loss=False, seed=0,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--classifier", default="qat",

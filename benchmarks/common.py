@@ -324,18 +324,6 @@ def percentile_stats(latencies_s) -> Dict[str, float]:
                      deployment-relevant 256-stream sweep points) is
                      written to ``METRICS_serve.json`` next to the
                      BENCH artifact
-      sparsity_speedup
-                     the tick-kernel claim: the fused delta tick
-                     benched against ITSELF across ΔGRU thresholds
-                     (rows[] of {theta, mean_ms, ticks_per_s,
-                     sparsity} at 64 streams, fv ticks, on the fused
-                     tier the platform executes — "fused-pallas" on
-                     TPU, else "fused-interpret").
-                     "speedup_vs_dense" = t(θ=0)/t(θ=0.15); "ok"
-                     gates it >= 1.5x only when "gated" is true (a
-                     real accelerator ran the pallas tier), else None
-                     — on CPU only "monotone_in_theta" (fused tick
-                     times non-increasing in θ) is meaningful
     """
     lat = np.asarray(latencies_s, np.float64) * 1e3
     return {

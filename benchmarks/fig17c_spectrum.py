@@ -51,4 +51,7 @@ def jnp_u(u):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -3,18 +3,6 @@ into the EXPERIMENTS.md §Roofline table. Run the dry-run first:
 
   PYTHONPATH=src python -m repro.launch.dryrun --all --out results/dryrun
   PYTHONPATH=src python -m repro.launch.dryrun --all --multi-pod --out results/dryrun
-
-Alongside the analytic table, `measure_fused_tick()` contributes two
-MEASURED points from the one-kernel serving tick
-(`repro.kernels.tick_fused`): the fused delta tick at θ=0
-(dense-equivalent — every Δ column fires) and at θ=0.15 (the
-fig_delta_tradeoff operating point), each with its wall-clock ms/tick,
-measured effective-MAC fraction, and achieved MAC/s against the
-classifier's offered work. The pair is the roofline-facing view of the
-sparsity_speedup claim in BENCH_serve.json: on the compiled pallas
-tier the θ>0 point should sit at the SAME achieved useful-MAC/s but
-lower latency, because the gather-compacted column update skips the
-work instead of masking it.
 """
 
 import glob
@@ -22,64 +10,6 @@ import json
 import os
 
 RESULTS = os.environ.get("DRYRUN_RESULTS", "results/dryrun")
-
-
-def measure_fused_tick(n_streams=64, n_ticks=20, thetas=(0.0, 0.15)):
-    """Measured fused-tick points: (θ, ms/tick, eff-MAC fraction,
-    achieved offered-MAC/s). Self-contained — builds its own synthetic
-    pipeline; uses the fused tier this platform executes (fused-pallas
-    on TPU, fused-interpret elsewhere)."""
-    import time
-
-    import jax
-    import numpy as np
-
-    from benchmarks.serve_load import WARMUP, _pipeline, _traffic
-    from repro.serving.serve_loop import StreamingKWSServer
-
-    impl = (
-        "fused-pallas" if jax.default_backend() == "tpu"
-        else "fused-interpret"
-    )
-    points = []
-    for theta in thetas:
-        pipe = _pipeline("delta", theta=theta)
-        params = pipe.init_params(jax.random.PRNGKey(0))
-        srv = StreamingKWSServer(
-            pipe, params, max_streams=n_streams, tick_impl=impl
-        )
-        for sid in range(n_streams):
-            srv.open_stream(sid)
-        slabs, _ = _traffic(pipe, n_streams, n_streams, "fv")
-        lat = []
-        for t in range(WARMUP + n_ticks):
-            slab, mask = slabs[t % len(slabs)]
-            t0 = time.perf_counter()
-            srv.step_batch(slab, mask)
-            if t >= WARMUP:
-                lat.append(time.perf_counter() - t0)
-        mean_s = float(np.mean(lat))
-        # offered classifier work per stream-tick (dense MAC count:
-        # 3H(I+H) + 3H(2H) per-layer gates + the FC head)
-        g = pipe.config.gru
-        offered = 0
-        in_dim = g.input_dim
-        for _layer in range(g.num_layers):
-            offered += 3 * g.hidden_dim * (in_dim + g.hidden_dim)
-            in_dim = g.hidden_dim
-        offered += g.hidden_dim * g.num_classes
-        slots = list(srv.active.values())
-        frac = float(np.mean(srv.sparsity[slots]))
-        points.append({
-            "theta": theta,
-            "tick_impl": impl,
-            "jax_backend": jax.default_backend(),
-            "ms_per_tick": mean_s * 1e3,
-            "eff_mac_fraction": frac,
-            "offered_mac_per_s": offered * n_streams / mean_s,
-            "useful_mac_per_s": offered * n_streams * frac / mean_s,
-        })
-    return points
 
 
 def load_reports(tag="sp"):
@@ -119,22 +49,13 @@ def run(seed: int = 0):
     ok = len(reports) >= 33 and len(mp) >= 33
     print(f"  claim (full matrix compiles on both meshes): "
           f"{'PASS' if ok else 'FAIL'}")
-    print("== Measured: one-kernel serving tick (repro.kernels."
-          "tick_fused) ==")
-    tick_points = measure_fused_tick()
-    for p in tick_points:
-        print(
-            f"  fused tick ({p['tick_impl']}, {p['jax_backend']}) "
-            f"theta={p['theta']:.2f}: {p['ms_per_tick']:7.2f} ms/tick  "
-            f"eff-MAC {p['eff_mac_fraction']:.3f}  "
-            f"offered {p['offered_mac_per_s'] / 1e6:8.1f} MMAC/s  "
-            f"useful {p['useful_mac_per_s'] / 1e6:8.1f} MMAC/s"
-        )
     return {
         "ok": ok, "n": len(reports), "n_mp": len(mp),
-        "fused_tick": tick_points,
     }
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

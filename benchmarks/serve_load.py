@@ -68,14 +68,7 @@ correctness-shaped sweeps). Every row records the resolved
 ``tick_impl``, the kernel dispatch tier it ran (``tick_dispatch``:
 "xla" / "pallas" / "interpret"), and the jax backend
 (``jax_backend``), so artifacts from different platforms stay
-comparable. Independent of the sweep, the payload carries a
-``sparsity_speedup`` block benching the fused delta tick against
-itself across ΔGRU thresholds (θ=0 dense-equivalent vs θ>0): the
-gather-compacted column update turns temporal sparsity into wall-clock
-tick speed, and the block's ``speedup_vs_dense`` (θ=0 time / θ=0.15
-time) is gated >= 1.5x on real accelerators (recorded, not gated, on
-CPU — where only the θ-monotonicity of the fused tick times is
-meaningful).
+comparable.
 
 Devices (``--devices``, default "auto"): every row records the device
 count it ran on. Counts > 1 build the server on a ``("stream",)`` mesh
@@ -478,96 +471,6 @@ def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
     return row
 
 
-# θ points of the sparsity-speedup block: θ=0 is the dense-equivalent
-# fused tick (every column fires), 0.15 is the fig_delta_tradeoff
-# operating point the headline gate compares against, 0.3 extends the
-# monotonicity check
-SPARSITY_THETAS = (0.0, 0.15, 0.3)
-SPARSITY_STREAMS = 64
-SPEEDUP_FLOOR = 1.5
-
-
-def _bench_sparsity_speedup(n_ticks):
-    """Fused delta tick vs ITSELF across ΔGRU thresholds.
-
-    The megakernel's gather-compacted column update does work
-    proportional to the fire count, so the θ=0.15 tick should beat the
-    θ=0 (dense-equivalent) tick on wall clock — temporal sparsity as
-    latency, not just a counter. Benched on the fused tier this
-    platform executes ("fused-pallas" on TPU, else "fused-interpret"):
-    ``speedup_vs_dense`` = t(θ=0)/t(θ=0.15) is gated >= 1.5x only when
-    the pallas tier actually ran (a real accelerator); on CPU the
-    interpreter's per-block overhead swamps the MAC savings, so the
-    block records the times and the θ-monotonicity without gating.
-    """
-    impl = (
-        "fused-pallas" if jax.default_backend() == "tpu"
-        else "fused-interpret"
-    )
-    rows = []
-    for theta in SPARSITY_THETAS:
-        pipe = _pipeline("delta", theta=theta)
-        params = pipe.init_params(jax.random.PRNGKey(0))
-        srv = StreamingKWSServer(
-            pipe, params, max_streams=SPARSITY_STREAMS, tick_impl=impl
-        )
-        for sid in range(SPARSITY_STREAMS):
-            srv.open_stream(sid)
-        slabs, _ = _traffic(pipe, SPARSITY_STREAMS, SPARSITY_STREAMS, "fv")
-        lat = []
-        for t in range(WARMUP + n_ticks):
-            slab, mask = slabs[t % len(slabs)]
-            t0 = time.perf_counter()
-            srv.step_batch(slab, mask)
-            if t >= WARMUP:
-                lat.append(time.perf_counter() - t0)
-        slots = list(srv.active.values())
-        rows.append({
-            "theta": theta,
-            "mean_ms": float(np.mean(lat)) * 1e3,
-            "ticks_per_s": 1.0 / float(np.mean(lat)),
-            "sparsity": float(np.mean(srv.sparsity[slots])),
-        })
-        print(
-            f"  sparsity-speedup {impl}: theta={theta:.2f} "
-            f"{rows[-1]['mean_ms']:7.2f} ms/tick  "
-            f"eff-MAC {rows[-1]['sparsity']:.3f}"
-        )
-    dense = rows[0]
-    sparse = next(r for r in rows if r["theta"] == 0.15)
-    speedup = dense["mean_ms"] / sparse["mean_ms"]
-    # 5% timing-noise tolerance: adjacent θ points with near-equal fire
-    # counts (e.g. 0.15 vs 0.3 on already-sparse traffic) jitter within
-    # a host scheduler quantum
-    monotone = all(
-        rows[i + 1]["mean_ms"] <= rows[i]["mean_ms"] * 1.05
-        for i in range(len(rows) - 1)
-    )
-    gated = jax.default_backend() in ("tpu", "gpu")
-    return {
-        "what": (
-            f"fused delta tick at theta=0.15 beats its own theta=0 "
-            f"(dense-equivalent) tick by >= {SPEEDUP_FLOOR}x at "
-            f"{SPARSITY_STREAMS} streams, fv ticks; gated on real "
-            f"accelerators, recorded (with theta-monotonicity) on CPU"
-        ),
-        "tick_impl": impl,
-        "tick_dispatch": _TICK_DISPATCH_TIER[impl],
-        "jax_backend": jax.default_backend(),
-        "rows": rows,
-        "speedup_vs_dense": speedup,
-        "monotone_in_theta": monotone,
-        "gated": gated,
-        "ok": (speedup >= SPEEDUP_FLOOR) if gated else None,
-    }
-
-
-# mirrors repro.serving.serve_loop._TICK_DISPATCH for the artifact
-_TICK_DISPATCH_TIER = {
-    "xla": "xla", "fused-pallas": "pallas", "fused-interpret": "interpret",
-}
-
-
 def _bench_metrics_overhead(n_ticks):
     """Measured cost of `metrics=`: fused fv ticks, metrics-on vs -off.
 
@@ -846,9 +749,6 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
             "scan_fv_ticks_per_s": row["ticks_per_s"],
             "vs_single_device": row["ticks_per_s"] / base["ticks_per_s"],
         })
-    # the tick-kernel's own claim: sparsity -> wall clock, fused tick vs
-    # itself across θ (independent of the sweep's tick_impl choice)
-    sparsity_speedup = _bench_sparsity_speedup(max(10, N_TICKS // 2))
     # the observability layer's own claim: metrics cost < 5% of a
     # fused tick (measured, recorded, and gated with the SLO)
     metrics_overhead, overhead_snapshot = _bench_metrics_overhead(
@@ -879,7 +779,6 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
         "scaling": scaling,
         "claim": claim,
         "slo": slo,
-        "sparsity_speedup": sparsity_speedup,
         "metrics_overhead": metrics_overhead,
     }
     with open("BENCH_serve.json", "w") as f:
@@ -941,18 +840,6 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
             f"vs scan ceiling: {rat} (floor {slo['min_vs_scan']:.2f}x)"
             f"  [{'PASS' if slo['ok'] else 'FAIL'}]"
         )
-    ss = sparsity_speedup
-    verdict = (
-        f"[{'PASS' if ss['ok'] else 'FAIL'}]" if ss["gated"]
-        else f"[recorded; monotone_in_theta="
-             f"{'yes' if ss['monotone_in_theta'] else 'no'}]"
-    )
-    print(
-        f"serve_load sparsity-speedup ({ss['tick_impl']}, "
-        f"{ss['jax_backend']}): theta=0.15 fused delta tick is "
-        f"{ss['speedup_vs_dense']:.2f}x its theta=0 self "
-        f"(floor {SPEEDUP_FLOOR}x on accelerators)  {verdict}"
-    )
     mo = metrics_overhead
     print(
         f"serve_load metrics-overhead: metrics-on fused tick "
@@ -978,6 +865,9 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--classifier", default="all",
@@ -1019,7 +909,7 @@ if __name__ == "__main__":
         choices=["auto", "xla", "fused-pallas", "fused-interpret"],
         help="tick implementation for every non-legacy server "
              "(StreamingKWSServer(tick_impl=...)): 'auto' = "
-             "fused-pallas on TPU, xla elsewhere; 'fused-interpret' "
+             "xla on every platform; 'fused-interpret' "
              "runs the megakernel under the Pallas interpreter "
              "(correctness-shaped, interpreter-slow on CPU). Rows "
              "record the resolved impl + dispatch tier",

@@ -44,6 +44,7 @@ import numpy as np
 from repro.core import quant
 from repro.core.fex import FExConfig, FExNormStats, fex_frames
 from repro.core.pipeline import KWSPipeline, KWSPipelineConfig
+from repro.compile_cache import enable_compile_cache
 from repro.data.gscd import CLASSES, make_dataset
 from repro.serving.serve_loop import StreamingKWSServer
 
@@ -124,6 +125,7 @@ def main():
                          "CPU with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # corpus + norm stats + a model (random weights for the demo)
     data = make_dataset(6, seed=0)
@@ -251,7 +253,9 @@ def main():
     rt_streams = args.streams * (16.0 / per_frame)
     print(f"wall {wall:.2f}s -> {per_frame:.2f} ms per batched "
           f"audio-in tick (FEx + GRU); real-time capacity at this batch "
-          f"~{rt_streams:.0f} streams/host (CPU)")
+          f"~{rt_streams:.0f} streams/host "
+          f"({jax.devices()[0].device_kind}, first tick's compile "
+          f"included)")
     top_counts = {}
     for sid, cls in detections.items():
         top_counts[CLASSES[cls]] = top_counts.get(CLASSES[cls], 0) + 1

@@ -95,10 +95,7 @@ def main():
     if args.dp:
         from jax.sharding import PartitionSpec as P
 
-        try:  # jax >= 0.5 exposes shard_map at the top level
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from repro.distributed.collectives import (
             compressed_psum_with_error_feedback, init_residual)

@@ -105,16 +105,9 @@ def masked_select(mask: jnp.ndarray, new_tree: Any, old_tree: Any) -> Any:
 
 def _register_dataclass_pytree(cls, data_fields):
     """Make a frozen dataclass a jax pytree (all listed fields are leaves)."""
-    try:
-        jax.tree_util.register_dataclass(
-            cls, data_fields=list(data_fields), meta_fields=[]
-        )
-    except (AttributeError, TypeError):  # very old jax — manual fallback
-        jax.tree_util.register_pytree_node(
-            cls,
-            lambda s: (tuple(getattr(s, f) for f in data_fields), None),
-            lambda _, xs: cls(**dict(zip(data_fields, xs))),
-        )
+    jax.tree_util.register_dataclass(
+        cls, data_fields=list(data_fields), meta_fields=[]
+    )
 
 
 # FExNormStats / TDFExState predate this module; register them here so a

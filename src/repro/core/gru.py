@@ -37,6 +37,7 @@ __all__ = [
     "init_gru_classifier",
     "gru_cell",
     "gru_layer",
+    "qdot",
     "fc_logits",
     "gru_classifier_forward",
     "gru_classifier_step",
@@ -95,6 +96,27 @@ def _maybe_q(x: jnp.ndarray, spec: Optional[quant.QuantSpec]) -> jnp.ndarray:
     return quant.fake_quant(x, spec) if spec is not None else x
 
 
+def qdot(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """``x @ w`` at float32 precision on every backend.
+
+    At default precision a TPU multiplies float32 in one bfloat16 pass,
+    which keeps 8 of a Q6.8 code's 14 significant bits. HIGHEST splits
+    the operands into bfloat16 pieces that hold every Q6.8 x int8
+    product exactly, as the CPU's float32 does, so the QAT forward stays
+    replayable on integer codes (repro.core.gru_int) on a TPU too.
+    """
+    return jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST)
+
+
+def _gate_acts(aspec):
+    """The gates' sigmoid and tanh: float, or on the Q6.8 grid the ROM
+    lookups (`quant.rom_sigmoid`), which give every backend the codes
+    the CPU computes."""
+    if aspec is None:
+        return jax.nn.sigmoid, jnp.tanh
+    return quant.rom_sigmoid, quant.rom_tanh
+
+
 def _layer_weights(layer: Params, wspec) -> Tuple[jnp.ndarray, ...]:
     # Biases are pre-loaded into the 24-bit HPE accumulator, which works
     # at the Q6.8 x int8 product scale (frac 15) — quantize them to that
@@ -121,18 +143,20 @@ def gru_cell(
     w_i, w_h, b_i, b_h = _layer_weights(layer, wspec)
     hdim = h.shape[-1]
 
-    gi = _maybe_q(x @ w_i + b_i, aspec)  # (B, 3H)
-    gh = _maybe_q(h @ w_h + b_h, aspec)
+    gi = _maybe_q(qdot(x, w_i) + b_i, aspec)  # (B, 3H)
+    gh = _maybe_q(qdot(h, w_h) + b_h, aspec)
     i_r, i_z, i_n = jnp.split(gi, 3, axis=-1)
     h_r, h_z, h_n = jnp.split(gh, 3, axis=-1)
     # Gate outputs are quantized BEFORE use: on the IC sigmoid/tanh are
     # Q6.8 ROM lookups, so every downstream consumer (the r * h_n
     # product and the convex h update) sees register values, never the
-    # float intermediate. This keeps the QAT forward bit-replayable on
-    # integer codes (repro.core.gru_int).
-    r = _maybe_q(jax.nn.sigmoid(i_r + h_r), aspec)
-    z = _maybe_q(jax.nn.sigmoid(i_z + h_z), aspec)
-    n = _maybe_q(jnp.tanh(i_n + _maybe_q(r * h_n, aspec)), aspec)
+    # float intermediate. The QAT path reads the integer engine's ROMs,
+    # which keeps it bit-replayable on integer codes (repro.core.gru_int)
+    # on every backend.
+    sigmoid, tanh = _gate_acts(aspec)
+    r = sigmoid(i_r + h_r)
+    z = sigmoid(i_z + h_z)
+    n = tanh(i_n + _maybe_q(r * h_n, aspec))
     h_new = (1.0 - z) * n + z * h
     return _maybe_q(h_new, aspec)
 
@@ -166,7 +190,7 @@ def fc_logits(params: Params, x: jnp.ndarray, config: GRUConfig):
     aspec = config.act_spec if config.quantized else None
     bspec = None if wspec is None else quant.BIAS_Q8_15
     w = _maybe_q(params["fc"]["w"], wspec)
-    return _maybe_q(x @ w + _maybe_q(params["fc"]["b"], bspec), aspec)
+    return _maybe_q(qdot(x, w) + _maybe_q(params["fc"]["b"], bspec), aspec)
 
 
 def gru_classifier_forward(

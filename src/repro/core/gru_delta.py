@@ -79,7 +79,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quant
-from repro.core.gru import GRUConfig, _layer_weights, _maybe_q, fc_logits
+from repro.core.gru import (
+    GRUConfig, _gate_acts, _layer_weights, _maybe_q, fc_logits, qdot,
+)
 from repro.core.gru_int import (
     _ACC_SHIFT,
     _ACT_SHIFT,
@@ -260,7 +262,7 @@ def delta_gru_cell(
     the thresholded deltas instead of densely from x and h.
 
     ``matmul`` overrides how a Δ·W contribution is evaluated (default:
-    dense ``dx @ w``). The fused-tick megakernel passes its gather-
+    dense `gru.qdot`). The fused-tick megakernel passes its gather-
     compacted sparse product here (`repro.kernels.tick_fused`), which
     multiplies only the firing columns — bit-identical by the grid
     argument above, but with work proportional to the fire count.
@@ -269,7 +271,7 @@ def delta_gru_cell(
     w_i, w_h, b_i, b_h = _layer_weights(layer, config.weight_spec)
     tx, th = thetas
     scale = quant.ACT_Q6_8.scale
-    mm = (lambda d, w: d @ w) if matmul is None else matmul
+    mm = qdot if matmul is None else matmul
 
     dx = x - st["x_ref"]
     fire_x = jnp.abs(dx) > tx * scale
@@ -287,9 +289,10 @@ def delta_gru_cell(
     gh = _maybe_q(acc_h + b_h, aspec)
     i_r, i_z, i_n = jnp.split(gi, 3, axis=-1)
     h_r, h_z, h_n = jnp.split(gh, 3, axis=-1)
-    r = _maybe_q(jax.nn.sigmoid(i_r + h_r), aspec)
-    z = _maybe_q(jax.nn.sigmoid(i_z + h_z), aspec)
-    n = _maybe_q(jnp.tanh(i_n + _maybe_q(r * h_n, aspec)), aspec)
+    sigmoid, tanh = _gate_acts(aspec)
+    r = sigmoid(i_r + h_r)
+    z = sigmoid(i_z + h_z)
+    n = tanh(i_n + _maybe_q(r * h_n, aspec))
     h_new = _maybe_q((1.0 - z) * n + z * st["h"], aspec)
 
     skipped, total = _count_macs(st, fire_x, fire_h)

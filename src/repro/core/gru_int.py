@@ -82,18 +82,11 @@ class QuantizedClassifier:
     fc_b: jnp.ndarray
 
 
-try:
-    jax.tree_util.register_dataclass(
-        QuantizedClassifier,
-        data_fields=["gru", "fc_w", "fc_b"],
-        meta_fields=[],
-    )
-except (AttributeError, TypeError):  # very old jax — manual fallback
-    jax.tree_util.register_pytree_node(
-        QuantizedClassifier,
-        lambda s: ((s.gru, s.fc_w, s.fc_b), None),
-        lambda _, xs: QuantizedClassifier(*xs),
-    )
+jax.tree_util.register_dataclass(
+    QuantizedClassifier,
+    data_fields=["gru", "fc_w", "fc_b"],
+    meta_fields=[],
+)
 
 
 def quantize_acts(x: jnp.ndarray) -> jnp.ndarray:

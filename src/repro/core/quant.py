@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "QuantSpec",
@@ -41,6 +42,8 @@ __all__ = [
     "tanh_lut_q68",
     "lut_sigmoid_q68",
     "lut_tanh_q68",
+    "rom_sigmoid",
+    "rom_tanh",
 ]
 
 
@@ -198,29 +201,50 @@ _LUT_MIN = 2 * ACT_Q6_8.qmin
 _LUT_MAX = 2 * ACT_Q6_8.qmax
 
 
+def _host_rom(fn) -> np.ndarray:
+    """``quantize_int(fn(code * 2^-8))`` over the LUT domain, evaluated
+    on the host CPU backend.
+
+    The TPU's float sigmoid/tanh differ from the CPU's in the last bit
+    at a few grid points (2 sigmoid and 6 tanh entries of 32767 on a
+    v5e), enough to flip a Q6.8 rounding. A ROM is one table, so it is
+    built on one backend: the integer engine and the QAT gates
+    (`rom_sigmoid`, `rom_tanh`) then serve the same codes on every
+    platform.
+    Built eagerly even when first requested under a trace (the cached
+    table must be a constant, not a tracer of the enclosing scan/jit).
+    """
+    try:
+        host = jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the Q6.8 sigmoid/tanh ROMs are built on the host CPU "
+            "backend, which this process does not have: leave "
+            "JAX_PLATFORMS unset or include 'cpu' (e.g. "
+            "JAX_PLATFORMS=tpu,cpu)"
+        ) from e
+    with jax.ensure_compile_time_eval(), jax.default_device(host):
+        codes = jnp.arange(_LUT_MIN, _LUT_MAX + 1, dtype=jnp.int32)
+        vals = fn(codes.astype(jnp.float32) * ACT_Q6_8.scale)
+        return np.asarray(quantize_int(vals, ACT_Q6_8))
+
+
 @functools.lru_cache(maxsize=None)
-def sigmoid_lut_q68() -> jnp.ndarray:
+def sigmoid_lut_q68() -> np.ndarray:
     """Q6.8 sigmoid ROM over the summed-preactivation code domain.
 
     Entry ``i`` holds ``quantize_int(sigmoid((i + _LUT_MIN) * 2^-8))`` —
     the same float evaluation + round-half-even the QAT path performs,
-    so lookup and fake-quant agree exactly on every representable input.
-    Built eagerly even when first requested under a trace (the cached
-    array must be a constant, not a tracer of the enclosing scan/jit).
+    so lookup and fake-quant agree exactly on every representable input
+    (on the CPU; see `_host_rom`).
     """
-    with jax.ensure_compile_time_eval():
-        codes = jnp.arange(_LUT_MIN, _LUT_MAX + 1, dtype=jnp.int32)
-        vals = jax.nn.sigmoid(codes.astype(jnp.float32) * ACT_Q6_8.scale)
-        return quantize_int(vals, ACT_Q6_8)
+    return _host_rom(jax.nn.sigmoid)
 
 
 @functools.lru_cache(maxsize=None)
-def tanh_lut_q68() -> jnp.ndarray:
+def tanh_lut_q68() -> np.ndarray:
     """Q6.8 tanh ROM over the summed-preactivation code domain."""
-    with jax.ensure_compile_time_eval():
-        codes = jnp.arange(_LUT_MIN, _LUT_MAX + 1, dtype=jnp.int32)
-        vals = jnp.tanh(codes.astype(jnp.float32) * ACT_Q6_8.scale)
-        return quantize_int(vals, ACT_Q6_8)
+    return _host_rom(jnp.tanh)
 
 
 def lut_sigmoid_q68(codes: jnp.ndarray) -> jnp.ndarray:
@@ -233,3 +257,29 @@ def lut_tanh_q68(codes: jnp.ndarray) -> jnp.ndarray:
     """Integer tanh: summed Q6.8 preactivation codes -> Q6.8 codes."""
     idx = jnp.clip(codes, _LUT_MIN, _LUT_MAX) - _LUT_MIN
     return jnp.take(tanh_lut_q68(), idx)
+
+
+def _rom_act(fn, lut):
+    """``fake_quant(fn(x), ACT_Q6_8)`` for Q6.8-grid ``x``, read from
+    the host ROM so every backend returns the same value, with the
+    gradient of the float expression (STE)."""
+
+    @jax.custom_jvp
+    def act(x):
+        codes = jnp.round(x * 2.0**ACT_Q6_8.frac_bits).astype(jnp.int32)
+        return dequantize_int(lut(codes), ACT_Q6_8)
+
+    @act.defjvp
+    def _jvp(primals, tangents):
+        _, dy = jax.jvp(lambda v: fake_quant(fn(v), ACT_Q6_8), primals,
+                        tangents)
+        return act(*primals), dy
+
+    return act
+
+
+# The QAT gate activations. On the CPU they equal fake_quant(fn(x))
+# bit for bit; a TPU's float sigmoid/tanh would round 8 grid points
+# differently (see `_host_rom`), two of them next to zero.
+rom_sigmoid = _rom_act(jax.nn.sigmoid, lut_sigmoid_q68)
+rom_tanh = _rom_act(jnp.tanh, lut_tanh_q68)

@@ -235,8 +235,9 @@ def stream_mesh(
         devs = visible[:devices]
     else:
         devs = list(devices)
-    if hasattr(jax, "make_mesh") and devs == list(jax.devices()):
-        return jax.make_mesh((len(devs),), (STREAM_AXIS,))
+    # Auto axis type: the serving programs shard by in/out shardings,
+    # and a slot reset's scatter must not need an explicit out_sharding
+    # (`jax.make_mesh` defaults to Explicit axes)
     return Mesh(np.asarray(devs), (STREAM_AXIS,))
 
 

@@ -12,46 +12,15 @@ interpret mode on CPU; BlockSpec layouts target TPU VMEM/MXU).
   wkv6       — state-resident RWKV6 recurrence (the §Perf cell-C lever)
 
 Dispatch-tier selection (pallas on TPU / interpret / reference, the
-legacy ``interpret=`` flag, the `force_dispatch` override, trace-aware
-no-nested-jit calls) is shared across all kernels: `repro.kernels.
-dispatch`.
+legacy ``interpret=`` flag, the `force_dispatch` override) is shared
+across all kernels: `repro.kernels.dispatch`.
 """
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# --- version-compat shim -------------------------------------------------
-# jax renamed the Mosaic compiler-params dataclass across releases:
-# `pltpu.CompilerParams` (old) -> `pltpu.TPUCompilerParams` -> (newer
-# releases again) `pltpu.CompilerParams`. Resolve whichever this jax
-# provides once, here, so every kernel builds against one spelling.
-_TPU_COMPILER_PARAMS_CLS = getattr(
-    _pltpu, "TPUCompilerParams", None
-) or getattr(_pltpu, "CompilerParams", None)
-
-
-def tpu_compiler_params(**kwargs):
-    """Build a pallas TPU compiler-params object on any supported jax.
-
-    Unknown keyword arguments (fields removed in some jax versions) are
-    dropped rather than raised, so kernels can always pass their full
-    intent (e.g. ``dimension_semantics``).
-    """
-    if _TPU_COMPILER_PARAMS_CLS is None:  # pragma: no cover
-        return None
-    import dataclasses
-
-    if dataclasses.is_dataclass(_TPU_COMPILER_PARAMS_CLS):
-        fields = {f.name for f in dataclasses.fields(_TPU_COMPILER_PARAMS_CLS)}
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-    return _TPU_COMPILER_PARAMS_CLS(**kwargs)
-
 
 from repro.kernels.dispatch import (
     DISPATCH_TIERS,
     dispatch_override,
     force_dispatch,
     resolve_dispatch,
-    trace_aware_jit,
 )
 from repro.kernels.fex_fused import fex_fused, fex_fused_ref
 from repro.kernels.gru import gru_sequence, gru_sequence_ref
@@ -69,9 +38,8 @@ from repro.kernels.tick_fused import (
 )
 
 __all__ = [
-    "tpu_compiler_params",
     "DISPATCH_TIERS", "dispatch_override", "force_dispatch",
-    "resolve_dispatch", "trace_aware_jit",
+    "resolve_dispatch",
     "fex_fused", "fex_fused_ref",
     "gru_sequence", "gru_sequence_ref",
     "intgemm", "intgemm_ref",

@@ -15,11 +15,7 @@ same three decisions; they are centralized here so `tdc` / `intgemm` /
 
   1. `resolve_dispatch` — map ("auto" | explicit tier, legacy
      ``interpret=`` flag) to a concrete tier for this jax backend.
-  2. `trace_aware_jit` — jit a kernel entry point at the top level but
-     inline it under an outer trace, so a caller's jit (the fused
-     serving tick, a training scan) compiles ONE program with no
-     nested-jit call boundary.
-  3. `force_dispatch` — a thread-local override consulted before
+  2. `force_dispatch` — a thread-local override consulted before
      everything else. The fused-tick megakernel body
      (`repro.kernels.tick_fused`) traces the whole serving tick —
      including classifier backends that themselves call `intgemm` —
@@ -33,7 +29,6 @@ same three decisions; they are centralized here so `tdc` / `intgemm` /
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 from typing import Optional
 
@@ -44,7 +39,6 @@ __all__ = [
     "dispatch_override",
     "force_dispatch",
     "resolve_dispatch",
-    "trace_aware_jit",
 ]
 
 DISPATCH_TIERS = ("pallas", "interpret", "reference")
@@ -85,6 +79,7 @@ def resolve_dispatch(
     interpret: Optional[bool] = None,
     *,
     off_tpu: str = "reference",
+    on_tpu: str = "pallas",
     has_reference: bool = True,
 ) -> str:
     """Resolve ('auto' | tier, legacy flag) to a concrete dispatch tier.
@@ -92,8 +87,9 @@ def resolve_dispatch(
     Precedence: an enclosing `force_dispatch` wins over everything;
     then the legacy ``interpret=`` flag (True -> "interpret", False ->
     "pallas"); then an explicit ``dispatch=`` tier; then "auto" picks
-    "pallas" on TPU and ``off_tpu`` elsewhere (each kernel states its
-    own off-TPU default: "reference" where the jnp formulation is the
+    ``on_tpu`` on TPU ("pallas" unless Mosaic refuses the kernel there)
+    and ``off_tpu`` elsewhere (each kernel states its own off-TPU
+    default: "reference" where the jnp formulation is the
     fast path, "interpret" where the interpreter is cheap enough to
     keep CI exercising the kernel body — `tdc` flips between the two
     on batch size).
@@ -118,26 +114,6 @@ def resolve_dispatch(
             return "interpret"
         return dispatch
     if jax.default_backend() == "tpu":
-        return "pallas"
+        return on_tpu
     return off_tpu
 
-
-def trace_aware_jit(fn, *, static_argnames=()):
-    """Wrap a kernel entry point: jit at top level, inline under a trace.
-
-    Batch shapes are static under tracing, so dispatch resolves the
-    same way inside an outer jit (e.g. the fused serving tick of
-    `repro.serving.serve_loop` or `KWSPipeline.features`) as at the
-    top level — but when already inside a trace the wrapper calls
-    ``fn`` directly instead of nesting another `jax.jit`, so the
-    caller's program keeps a single jaxpr with no inner call boundary.
-    """
-    jitted = jax.jit(fn, static_argnames=static_argnames)
-
-    @functools.wraps(fn)
-    def call(*args, **kwargs):
-        if jax.core.trace_state_clean():
-            return jitted(*args, **kwargs)
-        return fn(*args, **kwargs)
-
-    return call

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _fex_fused_kernel(
@@ -113,7 +112,7 @@ def fex_fused_pallas(
             pltpu.VMEM((block_batch, c), jnp.float32),
             pltpu.VMEM((block_batch, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

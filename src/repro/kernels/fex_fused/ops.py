@@ -2,23 +2,23 @@
 
 Tier selection (pallas on TPU, interpreter off-TPU — no standalone jnp
 reference, the interpreter IS the non-Mosaic evaluation of the same
-body) and the trace-aware jit discipline come from
-`repro.kernels.dispatch`, so the same call site works in CI (CPU,
-interpret validates the kernel body) and in production (TPU, compiled
-Mosaic kernel).
+body) comes from `repro.kernels.dispatch`, so the same call site works
+in CI (CPU, interpret validates the kernel body) and in production
+(TPU, compiled Mosaic kernel).
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.filters import BiquadCoeffs
-from repro.kernels.dispatch import resolve_dispatch, trace_aware_jit
+from repro.kernels.dispatch import resolve_dispatch
 from repro.kernels.fex_fused.kernel import fex_fused_pallas
 
-_fex_fused_call = trace_aware_jit(
+_fex_fused_call = jax.jit(
     fex_fused_pallas,
     static_argnames=("frame_len", "block_batch", "interpret"),
 )

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _gru_seq_kernel(
@@ -102,7 +101,7 @@ def gru_sequence_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((t, b, h), xs.dtype),
         scratch_shapes=[pltpu.VMEM((block_batch, h), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -2,20 +2,20 @@
 
 Tier selection (pallas on TPU, interpreter off-TPU — this kernel has
 no standalone jnp reference, the interpreter IS its non-Mosaic
-evaluation) and the trace-aware jit discipline come from
-`repro.kernels.dispatch`.
+evaluation) comes from `repro.kernels.dispatch`.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch import resolve_dispatch, trace_aware_jit
+from repro.kernels.dispatch import resolve_dispatch
 from repro.kernels.gru.kernel import gru_sequence_pallas
 
-_gru_seq_call = trace_aware_jit(
+_gru_seq_call = jax.jit(
     gru_sequence_pallas, static_argnames=("block_batch", "interpret")
 )
 

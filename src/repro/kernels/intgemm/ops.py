@@ -10,26 +10,26 @@
                     (`intgemm_ref`; fastest off-TPU, bit-identical to
                     the kernel for all in-range inputs).
 
-Tier selection, the legacy ``interpret=`` flag, the `force_dispatch`
-override, and the trace-aware no-nested-jit call discipline are the
-shared `repro.kernels.dispatch` machinery. The override matters here:
-`intgemm` is traced inside the fused-tick megakernel's body by the
-integer/delta-int classifier backends, where `force_dispatch
-("reference")` reroutes it to `intgemm_ref` (a `pallas_call` cannot
-nest).
+Tier selection, the legacy ``interpret=`` flag and the `force_dispatch`
+override are the shared `repro.kernels.dispatch` machinery. The
+override matters here: `intgemm` is traced inside the fused-tick
+megakernel's body by the integer/delta-int classifier backends, where
+`force_dispatch("reference")` reroutes it to `intgemm_ref` (a
+`pallas_call` cannot nest).
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch import resolve_dispatch, trace_aware_jit
+from repro.kernels.dispatch import resolve_dispatch
 from repro.kernels.intgemm.kernel import intgemm_pallas
 from repro.kernels.intgemm.ref import intgemm_ref
 
-_intgemm_call = trace_aware_jit(
+_intgemm_call = jax.jit(
     intgemm_pallas,
     static_argnames=("block_m", "block_n", "block_k", "interpret"),
 )
@@ -49,24 +49,30 @@ def resolve_intgemm_dispatch(
 
 
 def intgemm(
-    x: jnp.ndarray,  # (M, K) int (14-bit activation codes)
+    x: jnp.ndarray,  # (M, K) int activation codes, |x| < 2^14
     w: jnp.ndarray,  # (K, N) int8 weight codes
-    block_m: int = 8,
+    block_m: Optional[int] = None,
     block_n: int = 128,
     block_k: int = 128,
     interpret: Optional[bool] = None,
     dispatch: str = "auto",
 ) -> jnp.ndarray:
-    """Saturating-24-bit int matmul, any (M, K, N) via zero padding."""
+    """Saturating-24-bit int matmul, any (M, K, N) via zero padding.
+
+    ``block_m`` defaults to M rounded up to the int8 sublane tile (32),
+    capped at 512 rows: one grid step per 512 streams at serving width.
+    """
     path = resolve_intgemm_dispatch(dispatch, interpret)
     if path == "reference":
         return intgemm_ref(x, w)
     run_interpret = path == "interpret"
     m, k = x.shape
     n = w.shape[1]
+    if block_m is None:
+        block_m = min(512, -(-m // 32) * 32)
     pm, pk, pn = (-m) % block_m, (-k) % block_k, (-n) % block_n
     xp = jnp.pad(x.astype(jnp.int32), ((0, pm), (0, pk)))
-    wp = jnp.pad(w.astype(jnp.int32), ((0, pk), (0, pn)))
+    wp = jnp.pad(w.astype(jnp.int8), ((0, pk), (0, pn)))
     out = _intgemm_call(
         xp, wp,
         block_m=block_m, block_n=block_n, block_k=block_k,

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _tdc_kernel(
@@ -116,7 +115,7 @@ def tdc_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_frames, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_batch, c), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

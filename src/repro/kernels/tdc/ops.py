@@ -2,32 +2,34 @@
 
 `tdc_counts` picks one of three equivalent implementations per call:
 
-  * ``pallas``    — the compiled Mosaic kernel (TPU);
+  * ``pallas``    — the compiled Mosaic kernel (explicit only: Mosaic
+                    refuses it on v5e, see `resolve_tdc_dispatch`);
   * ``interpret`` — the same kernel body run by the Pallas interpreter
                     (validates kernel logic on CPU CI);
   * ``reference`` — the pure-jnp cumsum/floor formulation of
                     `repro.core.tdfex.sro_tdc` (fastest off-TPU, and the
                     fallback for shapes the kernel does not tile well).
 
-Tier selection, the legacy ``interpret=`` flag, the `force_dispatch`
-override, and the trace-aware no-nested-jit call discipline are the
-shared `repro.kernels.dispatch` machinery; this kernel's only local
-policy is the off-TPU auto split — small batches run the interpreter
-(cheap, keeps CI validating the kernel logic), large batches the
-vectorized jnp reference (the interpreter is per-element slow).
+Tier selection, the legacy ``interpret=`` flag and the `force_dispatch`
+override are the shared `repro.kernels.dispatch` machinery; this
+kernel's local policy is the reference on TPU and the off-TPU auto
+split — small batches run the interpreter (cheap, keeps CI validating
+the kernel logic), large batches the vectorized jnp reference (the
+interpreter is per-element slow).
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.tdfex import TDFExConfig, TDFExState, sro_tdc
-from repro.kernels.dispatch import resolve_dispatch, trace_aware_jit
+from repro.kernels.dispatch import resolve_dispatch
 from repro.kernels.tdc.kernel import tdc_pallas
 
-_tdc_call = trace_aware_jit(
+_tdc_call = jax.jit(
     tdc_pallas,
     static_argnames=(
         "samples_per_frame", "os", "f_tdc", "n_phases",
@@ -41,10 +43,17 @@ def resolve_tdc_dispatch(
     dispatch: str = "auto",
     interpret: Optional[bool] = None,
 ) -> str:
-    """Resolve 'auto' to a concrete path for this backend + batch shape."""
+    """Resolve 'auto' to a concrete path for this backend + batch shape.
+
+    On TPU 'auto' picks the reference: Mosaic refuses the kernel on v5e
+    (its (block, samples, channels) tiles pad 16 channels to 128 lanes
+    and overflow VMEM; tests/test_tpu_compile.py), so "pallas" stays an
+    explicit choice.
+    """
     return resolve_dispatch(
         dispatch, interpret,
         off_tpu="interpret" if batch <= 8 else "reference",
+        on_tpu="reference",
     )
 
 

@@ -55,8 +55,8 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.dispatch import force_dispatch
 from repro.kernels.intgemm.ref import INT24_MAX, INT24_MIN
 from repro.kernels.tick_fused.ref import tick_reference
@@ -377,7 +377,7 @@ def tick_fused_pallas(
             _stream_spec(o.shape, block_streams) for o in out_shapes_2d
         ],
         out_shape=out_shapes_2d,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -16,8 +16,10 @@ the product of its enclosing loops' trip counts, and derive:
                all-reduce 2B(n-1)/n, all-gather/reduce-scatter/all-to-all
                B(n-1)/n, collective-permute B; n = replica group size.
 
-Hardware model (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-50 GB/s/link ICI (single-link-serialized collectives — conservative).
+Hardware model: the dry-run cells target TPU v5e (`HARDWARE`), per chip
+197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link ICI (single-link-serialized
+collectives — conservative). `peaks_for` looks a measured device up by
+its `device_kind`; a device missing from `PEAKS` is an error.
 """
 
 from __future__ import annotations
@@ -28,31 +30,19 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 __all__ = [
-    "HARDWARE", "HLOAnalysis", "analyze_hlo", "CellReport", "make_report",
-    "peak_memory_bytes", "cost_analysis_dict",
+    "HARDWARE", "PEAKS", "peaks_for", "HLOAnalysis", "analyze_hlo",
+    "CellReport", "make_report", "peak_memory_bytes", "cost_analysis_dict",
 ]
 
 
 def peak_memory_bytes(ma) -> float:
-    """Per-device peak bytes from a CompiledMemoryStats, across jax
-    versions: older jaxlibs drop `peak_memory_in_bytes`, in which case
-    args + outputs + temps is the standard approximation."""
-    pk = getattr(ma, "peak_memory_in_bytes", None)
-    if pk:
-        return float(pk)
-    return float(
-        ma.argument_size_in_bytes
-        + ma.output_size_in_bytes
-        + ma.temp_size_in_bytes
-    )
+    """Per-device peak bytes from a CompiledMemoryStats."""
+    return float(ma.peak_memory_in_bytes)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a dict (pre-0.5 jax returns [dict])."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        return ca[0] if ca else {}
-    return dict(ca)
+    """compiled.cost_analysis() as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +53,20 @@ class Hardware:
     ici_bw: float = 50e9  # B/s per link
 
 
-HARDWARE = Hardware()
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"),
+# keyed by `jax.Device.device_kind`.
+PEAKS = {"TPU v5 lite": Hardware()}
+HARDWARE = PEAKS["TPU v5 lite"]
+
+
+def peaks_for(device_kind: str) -> Hardware:
+    """Peaks of the chip `device_kind` names; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no peak table entry for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models.layers import dense_init, mlp_apply, mlp_init
 

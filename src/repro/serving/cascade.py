@@ -171,7 +171,8 @@ def detector_scores(fv: jnp.ndarray, config: CascadeConfig) -> jnp.ndarray:
         return jnp.mean(jax.nn.relu(fv), axis=-1)
     w = jnp.asarray(config.linear_w, jnp.float32)
     b = jnp.float32(config.linear_b)
-    return jax.nn.sigmoid(fv @ w + b)
+    z = jnp.matmul(fv, w, precision=jax.lax.Precision.HIGHEST)
+    return jax.nn.sigmoid(z + b)
 
 
 def init_state(batch: int, device=None) -> Dict[str, jnp.ndarray]:

@@ -72,7 +72,12 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.tick_fused import tick_fused, tick_reference
+from repro.kernels.tick_fused import (
+    mosaic_unsupported,
+    over_stream_shards,
+    tick_fused,
+    tick_reference,
+)
 from repro.serving import cascade as cascade_lib
 
 from repro.distributed.sharding import (
@@ -242,18 +247,11 @@ class ServerState:
     det: Any = None
 
 
-try:
-    jax.tree_util.register_dataclass(
-        ServerState,
-        data_fields=["gru", "carry", "scores", "det"],
-        meta_fields=[],
-    )
-except (AttributeError, TypeError):  # very old jax — manual fallback
-    jax.tree_util.register_pytree_node(
-        ServerState,
-        lambda s: ((s.gru, s.carry, s.scores, s.det), None),
-        lambda _, xs: ServerState(*xs),
-    )
+jax.tree_util.register_dataclass(
+    ServerState,
+    data_fields=["gru", "carry", "scores", "det"],
+    meta_fields=[],
+)
 
 
 # kept importable for API compatibility with the pre-fused server
@@ -282,8 +280,8 @@ def _fused_tick(pipeline, raw_audio, params, state: ServerState, inp,
     so the megakernel can re-run it per stream block); this wrapper
     owns only the `ServerState` packing and the implementation choice:
 
-      tick_impl="xla"             one fused XLA program (the default
-                                  off-TPU; exactly the pre-kernel tick)
+      tick_impl="xla"             one fused XLA program (what "auto"
+                                  picks; exactly the pre-kernel tick)
       tick_impl="fused-pallas"    the whole tick as ONE `pallas_call`
                                   over stream blocks with the ΔGRU
                                   gather path (TPU)
@@ -291,16 +289,18 @@ def _fused_tick(pipeline, raw_audio, params, state: ServerState, inp,
                                   Pallas interpreter (CPU-testable)
 
     All three are bit-identical for every classifier backend (tests/
-    test_tick_fused.py). ``mesh`` threads the stream mesh to the kernel
-    tiers, whose `pallas_call` GSPMD cannot partition — the kernel
-    wraps itself in a `shard_map` so each device still runs one kernel
-    on its shard-local slab.
+    test_tick_fused.py). With a stream ``mesh`` every choice runs once
+    per shard-local slab under a `shard_map` (`over_stream_shards`):
+    GSPMD cannot partition a `pallas_call`, and the xla tick holds the
+    intgemm kernels on TPU.
     """
     state4 = (state.gru, state.carry, state.scores, state.det)
     if tick_impl == "xla":
-        (gru, carry, scores, det), out_scores, top = tick_reference(
-            pipeline, raw_audio, params, state4, inp, mask,
-            frontend_state, smoothing,
+        tick = over_stream_shards(
+            functools.partial(tick_reference, pipeline, raw_audio), mesh
+        )
+        (gru, carry, scores, det), out_scores, top = tick(
+            params, state4, inp, mask, frontend_state, smoothing,
         )
     else:
         (gru, carry, scores, det), out_scores, top = tick_fused(
@@ -366,9 +366,10 @@ class StreamingKWSServer:
     over stream blocks with the ΔGRU gather path — temporal sparsity
     becomes wall-clock speed), ``"fused-interpret"`` (the megakernel
     under the Pallas interpreter, for CPU CI), or ``"auto"`` (default:
-    fused-pallas on TPU, xla elsewhere). All choices are bit-identical
-    for every backend; the resolved choice and its kernel dispatch tier
-    are exposed as `srv.tick_impl` / `srv.tick_dispatch`.
+    xla on every platform — Mosaic cannot lower the megakernel for most
+    pipelines, see `repro.kernels.tick_fused.mosaic_unsupported`). The
+    resolved choice and its kernel dispatch tier are exposed as
+    `srv.tick_impl` / `srv.tick_dispatch`.
 
     Observability: ``metrics=`` takes a
     `repro.serving.metrics.MetricsRegistry` (or ``True`` for a fresh
@@ -404,12 +405,21 @@ class StreamingKWSServer:
                 f"{tick_impl!r}"
             )
         if tick_impl == "auto":
-            # the megakernel is only a wall-clock win compiled on TPU;
-            # off-TPU the fused-XLA tick is both fastest and the
-            # bit-identity reference
-            tick_impl = (
-                "fused-pallas" if jax.default_backend() == "tpu" else "xla"
-            )
+            # the fused-XLA tick is the one program that compiles for
+            # every backend and both input kinds on every platform; the
+            # megakernel stays an explicit choice (see
+            # `repro.kernels.tick_fused.mosaic_unsupported`)
+            tick_impl = "xla"
+        if tick_impl == "fused-pallas":
+            # FV_Norm is the megakernel's widest reach; a pipeline it
+            # cannot serve there it cannot serve at all (raw-audio ticks
+            # are refused per tick by `tick_fused`)
+            reason = mosaic_unsupported(pipeline, raw_audio=False)
+            if reason is not None:
+                raise ValueError(
+                    f"tick_impl='fused-pallas' cannot serve this "
+                    f"pipeline: {reason}; use tick_impl='xla'"
+                )
         self.tick_impl = tick_impl
         # the kernel dispatch tier the ticks will actually run
         # ("xla" = no pallas_call at all) — benchmarks record this

@@ -3,8 +3,14 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import quant
+from repro.core.gru_delta import (
+    DeltaConfig,
+    delta_classifier_step,
+    delta_init_states,
+)
 from repro.core.gru import (
     GRUConfig,
     classifier_macs,
@@ -70,6 +76,29 @@ def test_streaming_equals_full_forward():
     np.testing.assert_allclose(
         np.asarray(full), np.asarray(stream), atol=1e-6
     )
+
+
+@pytest.mark.parametrize("engine", ["qat", "delta"])
+def test_classifier_dots_ask_for_highest_precision(engine):
+    """A TPU runs a default-precision float32 dot as one bfloat16 pass,
+    which drops 6 of a Q6.8 code's 14 bits; every dot of the float-domain
+    classifiers must ask for HIGHEST so the chip computes what the CPU
+    does."""
+    cfg = GRUConfig(quantized=True)
+    params = init_gru_classifier(jax.random.PRNGKey(5), cfg)
+    fv = jnp.zeros((2, cfg.input_dim), jnp.float32)
+    if engine == "qat":
+        step = lambda st, x: gru_classifier_step(params, st, x, cfg)  # noqa: E731
+        states = init_states(cfg, 2)
+    else:
+        thetas = DeltaConfig(0.15, 0.15).code_thresholds(cfg.num_layers)
+        step = lambda st, x: delta_classifier_step(  # noqa: E731
+            params, st, x, cfg, thetas)
+        states = delta_init_states(cfg, 2)
+    text = jax.jit(step).lower(states, fv).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert len(dots) == 2 * cfg.num_layers + 1  # x·W_i, h·W_h per layer, FC
+    assert all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots), dots
 
 
 def test_paper_size_checks():

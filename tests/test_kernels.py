@@ -170,6 +170,47 @@ def test_intgemm_matches_integer_oracle_property(m, k, n, seed, full_scale):
     )
 
 
+@pytest.mark.parametrize("under_jit", [False, True],
+                         ids=["top-level", "under-jit"])
+def test_kernel_entry_points_run_at_top_level_and_under_jit(under_jit):
+    """intgemm and tdc_counts with dispatch="interpret" run both as
+    top-level calls (their own jit) and inlined into a caller's jit,
+    the way the serving tick traces them."""
+    cfg = TDFExConfig()
+    spf = cfg.decimation // cfg.tdc_oversample
+    x = jnp.asarray(RNG.integers(-8191, 8192, (5, 33)), jnp.int32)
+    w = jnp.asarray(RNG.integers(-128, 128, (33, 20)), jnp.int32)
+    u = jnp.asarray(
+        np.abs(RNG.standard_normal((2, spf * 2, 16))).astype(np.float32)
+        * 0.2
+    )
+
+    def both(x, w, u):
+        return (intgemm(x, w, dispatch="interpret"),
+                tdc_counts(u, cfg, dispatch="interpret"))
+
+    gemm, counts = (jax.jit(both) if under_jit else both)(x, w, u)
+    np.testing.assert_array_equal(np.asarray(gemm),
+                                  np.asarray(intgemm_ref(x, w)))
+    ref = tdc_counts_ref(
+        np.asarray(u), np.full(16, cfg.f_free_hz),
+        np.full(16, cfg.k_sro_hz), spf, cfg.tdc_oversample, cfg.f_tdc,
+    )
+    assert np.abs(np.asarray(counts) - ref).max() <= 1.0
+
+
+def test_intgemm_split_covers_delta_codes():
+    """The int8 hi/lo split is exact over |x| < 2^14, the range of Q6.8
+    codes and of their ΔGRU deltas (x - x_ref)."""
+    x = jnp.asarray([[-16383, -8192, -129, -128, -1, 0, 1, 127, 128,
+                      8191, 16383]], jnp.int32)
+    w = jnp.asarray(RNG.integers(-128, 128, (11, 9)), jnp.int8)
+    np.testing.assert_array_equal(
+        np.asarray(intgemm(x, w, dispatch="interpret")),
+        np.asarray(intgemm_ref(x, w)),
+    )
+
+
 # ---------------- tdc ----------------
 
 @pytest.mark.parametrize("b,frames,c", [(1, 3, 16), (3, 6, 16), (2, 4, 4)])

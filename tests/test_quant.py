@@ -52,3 +52,23 @@ def test_weight_int8_range():
     assert int(q.min()) >= -128 and int(q.max()) <= 127
     back = quant.dequantize_int(q, quant.WEIGHT_INT8)
     np.testing.assert_allclose(back, w, atol=quant.WEIGHT_INT8.scale / 2)
+
+
+@pytest.mark.parametrize("name", ["sigmoid", "tanh"])
+def test_rom_gates_equal_float_gates_on_cpu(name):
+    """The QAT gates read the host ROM. On the CPU, where the ROM was
+    built, they equal fake_quant(fn(x)) on the whole grid domain, in
+    value and in gradient."""
+    fn, rom = {"sigmoid": (jax.nn.sigmoid, quant.rom_sigmoid),
+               "tanh": (jnp.tanh, quant.rom_tanh)}[name]
+    spec = quant.ACT_Q6_8
+    x = jnp.arange(2 * spec.qmin, 2 * spec.qmax + 1) * spec.scale
+
+    def ref(v):
+        return quant.fake_quant(fn(v), spec)
+
+    got, want = jax.jit(rom)(x), jax.jit(ref)(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    grad = jax.vmap(jax.grad(rom))
+    np.testing.assert_array_equal(np.asarray(grad(x)),
+                                  np.asarray(jax.vmap(jax.grad(ref))(x)))

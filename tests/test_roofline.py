@@ -88,3 +88,12 @@ def test_hardware_constants_match_spec():
     assert HARDWARE.peak_flops == 197e12
     assert HARDWARE.hbm_bw == 819e9
     assert HARDWARE.ici_bw == 50e9
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    from repro.launch.roofline import PEAKS, peaks_for
+
+    assert peaks_for("TPU v5 lite") is HARDWARE
+    assert set(PEAKS) == {"TPU v5 lite"}
+    with pytest.raises(KeyError, match="no peak table entry"):
+        peaks_for("cpu")

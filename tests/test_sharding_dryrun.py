@@ -31,10 +31,7 @@ sys.path.insert(0, "src")
 import dataclasses
 import jax, jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 """
 
 

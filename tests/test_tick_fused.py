@@ -139,12 +139,43 @@ def test_tick_impl_validation_and_resolution(norm_stats, shared_params):
     assert srv.tick_impl == "fused-interpret"
     assert srv.tick_dispatch == "interpret"
     auto = StreamingKWSServer(pipe, shared_params, max_streams=4)
-    if jax.default_backend() == "tpu":  # pragma: no cover - TPU runner
-        assert auto.tick_impl == "fused-pallas"
-    else:
-        assert auto.tick_impl == "xla"
-    assert auto.tick_dispatch == ("pallas" if auto.tick_impl ==
-                                  "fused-pallas" else "xla")
+    assert auto.tick_impl == "xla"  # on every platform
+    assert auto.tick_dispatch == "xla"
+
+
+@pytest.mark.parametrize("classifier,cascade,match", [
+    ("qat", None, "gather"),
+    ("integer", None, "gather"),
+    ("delta-int", None, "gather"),
+    ("delta", None, "cumsum"),
+    ("qat", CascadeConfig(), "truncation"),
+])
+def test_fused_pallas_rejects_what_mosaic_cannot_lower(
+    norm_stats, shared_params, classifier, cascade, match
+):
+    """The compiled megakernel is refused, naming the primitive, at
+    construction — before anything is traced or compiled."""
+    with pytest.raises(ValueError, match=match):
+        StreamingKWSServer(
+            _pipe(norm_stats, classifier, cascade), shared_params,
+            max_streams=4, tick_impl="fused-pallas",
+        )
+
+
+def test_fused_pallas_rejects_raw_audio_before_any_state_change(
+    norm_stats, shared_params
+):
+    """float constructs (FV_Norm compiles), but a raw-audio tick is
+    refused at trace time and leaves the server's state untouched."""
+    srv = StreamingKWSServer(_pipe(norm_stats, "float"), shared_params,
+                             max_streams=4, tick_impl="fused-pallas")
+    srv.open_stream(0)
+    before = [np.asarray(x) for x in jax.tree_util.tree_leaves(srv.state)]
+    slab = np.zeros((4, srv.pipeline.chunk_samples), np.float32)
+    with pytest.raises(ValueError, match="scan"):
+        srv.step_batch(slab, np.ones((4,), bool))
+    for a, b in zip(before, jax.tree_util.tree_leaves(srv.state)):
+        np.testing.assert_array_equal(a, np.asarray(b))
 
 
 def test_resolve_tick_dispatch_off_tpu():
