@@ -34,6 +34,10 @@ def enable_compile_cache() -> str:
         path = str(DEFAULT_DIR)
         jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_compilation_cache_dir", path)
+    # key entries on the programs' metadata too: a program cached by a
+    # checkout with other named scopes (`tick_reference`'s kws_*) would
+    # otherwise come back with that checkout's op names in its profile
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # the serving programs compile in about a second each; cache them all
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path

@@ -111,19 +111,29 @@ def int_gru_cell(
     h: jnp.ndarray,
     x: jnp.ndarray,
     config: GRUConfig,
+    scope: str = "kws_gru",
 ) -> jnp.ndarray:
-    """One GRU step on codes: x (B, I), h (B, H) -> h' (B, H), int32."""
+    """One GRU step on codes: x (B, I), h (B, H) -> h' (B, H), int32.
+
+    Its stages run under named scopes ``<scope>_gemm`` (the input and
+    recurrent accumulations), ``<scope>_gates`` (the three ROM lookups)
+    and ``<scope>_update`` (the state update)."""
     del config  # geometry is carried by the code arrays themselves
-    gi = _accum(x, layer["w_i"], layer["b_i"])  # (B, 3H)
-    gh = _accum(h, layer["w_h"], layer["b_h"])
+    with jax.named_scope(f"{scope}_gemm"):
+        gi = _accum(x, layer["w_i"], layer["b_i"])  # (B, 3H)
+        gh = _accum(h, layer["w_h"], layer["b_h"])
     i_r, i_z, i_n = jnp.split(gi, 3, axis=-1)
     h_r, h_z, h_n = jnp.split(gh, 3, axis=-1)
-    r = quant.lut_sigmoid_q68(i_r + h_r)
-    z = quant.lut_sigmoid_q68(i_z + h_z)
-    rn = quant.clip_act_codes(quant.round_shift_even(r * h_n, _ACT_SHIFT))
-    n = quant.lut_tanh_q68(i_n + rn)
-    h_new = quant.round_shift_even((_ONE_Q68 - z) * n + z * h, _ACT_SHIFT)
-    return quant.clip_act_codes(h_new)
+    with jax.named_scope(f"{scope}_gates"):
+        r = quant.lut_sigmoid_q68(i_r + h_r)
+        z = quant.lut_sigmoid_q68(i_z + h_z)
+        rn = quant.clip_act_codes(
+            quant.round_shift_even(r * h_n, _ACT_SHIFT))
+        n = quant.lut_tanh_q68(i_n + rn)
+    with jax.named_scope(f"{scope}_update"):
+        h_new = quant.round_shift_even((_ONE_Q68 - z) * n + z * h,
+                                       _ACT_SHIFT)
+        return quant.clip_act_codes(h_new)
 
 
 def int_gru_layer(
@@ -169,11 +179,12 @@ def int_gru_classifier_step(
     """Streaming step on codes: one frame (B, C) -> (states, (B, K))."""
     new_states = []
     x = fv_t
-    for layer, h in zip(qparams.gru, states):
-        h_new = int_gru_cell(layer, h, x, config)
+    for i, (layer, h) in enumerate(zip(qparams.gru, states)):
+        h_new = int_gru_cell(layer, h, x, config, scope=f"kws_gru{i}")
         new_states.append(h_new)
         x = h_new
-    logits = _accum(x, qparams.fc_w, qparams.fc_b)
+    with jax.named_scope("kws_head"):
+        logits = _accum(x, qparams.fc_w, qparams.fc_b)
     return new_states, logits
 
 

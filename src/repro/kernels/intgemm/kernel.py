@@ -86,4 +86,5 @@ def intgemm_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="kws_intgemm",
     )(hi, lo, w.astype(jnp.int8))

@@ -75,40 +75,54 @@ def tick_reference(
     sleeps. An always-open gate makes ``wake == mask`` elementwise, so
     the tick is bit-identical to the non-cascaded program.
 
+    Each stage runs under a `jax.named_scope`, so its device ops carry
+    the scope in their HLO metadata (``op_name``): ``kws_frontend``
+    (raw audio only), ``kws_cascade``, ``kws_classifier`` — inside
+    which the integer classifier names ``kws_gru{l}_gemm`` /
+    ``kws_gru{l}_gates`` / ``kws_gru{l}_update`` and ``kws_head``
+    (`repro.core.gru_int.int_gru_classifier_step`) — and
+    ``kws_smooth`` (softmax, smoothing, masked selects, argmax).
+    Scopes change metadata only, never a value.
+
     Returns ``((gru, carry, scores, det), scores, top)``.
     """
     gru_in, carry_in, scores_in, det_in = state
     if raw_audio:
-        new_carry, fv = pipeline.streaming_features_apply(
-            carry_in, inp, frontend_state
-        )
-        carry = masked_select(mask, new_carry, carry_in)
+        with jax.named_scope("kws_frontend"):
+            new_carry, fv = pipeline.streaming_features_apply(
+                carry_in, inp, frontend_state
+            )
+            carry = masked_select(mask, new_carry, carry_in)
     else:
         carry = carry_in
         fv = inp
     casc = pipeline.config.cascade
     if casc is not None:
-        score = cascade_lib.detector_scores(fv, casc)
-        new_det, gate = cascade_lib.gate_step(det_in, score, casc)
-        det = masked_select(mask, new_det, det_in)
-        wake = jnp.logical_and(mask, gate)
+        with jax.named_scope("kws_cascade"):
+            score = cascade_lib.detector_scores(fv, casc)
+            new_det, gate = cascade_lib.gate_step(det_in, score, casc)
+            det = masked_select(mask, new_det, det_in)
+            wake = jnp.logical_and(mask, gate)
     else:
         det = det_in
         wake = mask
-    if step_fn is None:
-        new_gru, logits = pipeline.streaming_logits_apply(
-            params, list(gru_in), fv
-        )
-    else:
-        new_gru, logits = step_fn(params, list(gru_in), fv, wake)
-    gru = tuple(masked_select(wake, tuple(new_gru), tuple(gru_in)))
-    probs = jax.nn.softmax(logits, axis=-1)
-    smoothed = smoothing * scores_in + (1.0 - smoothing) * probs
-    scores = masked_select(wake, smoothed, scores_in)
-    if casc is not None and casc.score_decay != 1.0:
-        # submitted but gated: decay the stale posterior toward zero
-        # ("silence") while the classifier sleeps
-        gated = jnp.logical_and(mask, jnp.logical_not(wake))
-        scores = masked_select(gated, casc.score_decay * scores_in, scores)
-    top = jnp.argmax(scores, axis=-1)
+    with jax.named_scope("kws_classifier"):
+        if step_fn is None:
+            new_gru, logits = pipeline.streaming_logits_apply(
+                params, list(gru_in), fv
+            )
+        else:
+            new_gru, logits = step_fn(params, list(gru_in), fv, wake)
+    with jax.named_scope("kws_smooth"):
+        gru = tuple(masked_select(wake, tuple(new_gru), tuple(gru_in)))
+        probs = jax.nn.softmax(logits, axis=-1)
+        smoothed = smoothing * scores_in + (1.0 - smoothing) * probs
+        scores = masked_select(wake, smoothed, scores_in)
+        if casc is not None and casc.score_decay != 1.0:
+            # submitted but gated: decay the stale posterior toward zero
+            # ("silence") while the classifier sleeps
+            gated = jnp.logical_and(mask, jnp.logical_not(wake))
+            scores = masked_select(gated, casc.score_decay * scores_in,
+                                   scores)
+        top = jnp.argmax(scores, axis=-1)
     return (gru, carry, scores, det), scores, top

@@ -47,7 +47,10 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
+
+from repro.serving.metrics import span
 
 __all__ = [
     "TickHandle",
@@ -76,21 +79,26 @@ class TickHandle:
     polled `ready()` and fetched later recorded the fetch time, not
     the completion time, inflating its submit-to-scores latency.)
 
-    `fetch_hist`, when given, is a `repro.serving.metrics.Histogram`
-    that receives the milliseconds the first `result()` spent blocked
-    materializing host arrays (the server wires its
-    ``kws_serve_tick_fetch_ms`` here when metrics are enabled).
+    The first `result()` runs under the ``kws.handle.fetch`` span
+    (children ``kws.handle.wait``, ``kws.handle.d2h``) tagged with
+    `tick`, the server's dispatch number, and observed into
+    `fetch_hist` when given (the server's ``kws_serve_tick_fetch_ms``).
+    `dispatched_at` is the end of the dispatch span.
     """
 
-    __slots__ = ("_scores", "_top", "_host", "meta", "done_at",
-                 "_fetch_hist", "_clock")
+    __slots__ = ("_scores", "_top", "_host", "meta", "tick",
+                 "dispatched_at", "done_at", "_fetch_hist", "_clock")
 
-    def __init__(self, scores, top, meta: Any = None, fetch_hist=None,
+    def __init__(self, scores, top, meta: Any = None,
+                 tick: Optional[int] = None,
+                 dispatched_at: Optional[float] = None, fetch_hist=None,
                  clock: Callable[[], float] = time.perf_counter):
         self._scores = scores
         self._top = top
         self._host: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self.meta = meta
+        self.tick = tick
+        self.dispatched_at = dispatched_at
         self.done_at: Optional[float] = None
         self._fetch_hist = fetch_hist
         self._clock = clock
@@ -114,14 +122,15 @@ class TickHandle:
         cached copy, so fetching a handle after further ticks (or slot
         resets) ran is always safe."""
         if self._host is None:
-            t0 = self._clock()
-            self._host = (np.array(self._scores), np.array(self._top))
+            with span("kws.handle.fetch", self._fetch_hist, self._clock,
+                      tick=self.tick) as sp:
+                with span("kws.handle.wait", tick=self.tick):
+                    jax.block_until_ready((self._scores, self._top))
+                with span("kws.handle.d2h", tick=self.tick):
+                    self._host = (np.array(self._scores), np.array(self._top))
             self._scores = self._top = None
-            t1 = self._clock()
             if self.done_at is None:
-                self.done_at = t1
-            if self._fetch_hist is not None:
-                self._fetch_hist.observe((t1 - t0) * 1e3)
+                self.done_at = sp.end
         return self._host
 
     @property
@@ -195,9 +204,10 @@ class PipelinedIngress:
         self._metas: List[Any] = []
         self._staged = False
         # observability rides the server's registry: one TickTrace per
-        # STAGED tick (stage -> commit -> dispatch -> retire marks; a
-        # window of K ticks shares the dispatch/retire timestamps of
-        # its one device call), plus in-flight / pending-window gauges.
+        # STAGED tick (stage -> commit -> dispatch -> retire marks, from
+        # span boundaries; a window of K ticks shares the dispatch/retire
+        # timestamps of its one device call), plus in-flight / pending-
+        # window gauges.
         # All host clock reads around the existing calls — operands and
         # dispatch order are untouched, so the pipelined path stays
         # bit-identical with metrics on.
@@ -234,44 +244,48 @@ class PipelinedIngress:
         dispatch)."""
         if self._staged:
             raise RuntimeError("stage() called again before commit()")
-        n = self.server.max_streams
-        if n != self._slabs[0].shape[1]:
-            # The server was resized (autoscaler / shard-loss
-            # recovery): the preallocated buffers are the wrong
-            # capacity. Reallocating is only safe with the pipeline
-            # empty — in-flight dispatches and half-filled windows
-            # still hold old-capacity slabs — so callers drain()
-            # around a resize and the next stage() picks up the new
-            # capacity here.
-            if self._fifo or self._fill:
-                raise RuntimeError(
-                    "server capacity changed mid-pipeline: drain() "
-                    "the ingress before staging into the resized "
-                    "server"
-                )
-            self._slabs = [
-                np.zeros((self.window, n, self.dim), np.float32)
-                for _ in range(self.depth)
-            ]
-            self._masks = [
-                np.zeros((self.window, n), bool)
-                for _ in range(self.depth)
-            ]
-        i = self._cursor
-        if self._fill == 0:
-            # about to write row 0 of buffer i: the dispatch that
-            # consumed it (if any) is the FIFO front — buffers cycle
-            # round-robin and retire in dispatch order
-            while self._fifo and self._fifo[0][0] == i:
-                self._retire(*self._fifo.popleft()[1:])
+        k = self.server.dispatch_seq
+        with span("kws.ingress.stage", clock=self.server._clock,
+                  tick=k) as sp:
+            n = self.server.max_streams
+            if n != self._slabs[0].shape[1]:
+                # The server was resized (autoscaler / shard-loss
+                # recovery): the preallocated buffers are the wrong
+                # capacity. Reallocating is only safe with the pipeline
+                # empty — in-flight dispatches and half-filled windows
+                # still hold old-capacity slabs — so callers drain()
+                # around a resize and the next stage() picks up the new
+                # capacity here.
+                if self._fifo or self._fill:
+                    raise RuntimeError(
+                        "server capacity changed mid-pipeline: drain() "
+                        "the ingress before staging into the resized "
+                        "server"
+                    )
+                self._slabs = [
+                    np.zeros((self.window, n, self.dim), np.float32)
+                    for _ in range(self.depth)
+                ]
+                self._masks = [
+                    np.zeros((self.window, n), bool)
+                    for _ in range(self.depth)
+                ]
+            i = self._cursor
+            if self._fill == 0 and self._fifo and self._fifo[0][0] == i:
+                # about to write row 0 of buffer i: the dispatch that
+                # consumed it is the FIFO front — buffers cycle
+                # round-robin and retire in dispatch order
+                with span("kws.ingress.reuse_wait", tick=k):
+                    while self._fifo and self._fifo[0][0] == i:
+                        self._retire(*self._fifo.popleft()[1:])
+            mask = self._masks[i][self._fill]
+            mask[:] = False
         self._staged = True
         if self.metrics is not None:
             tr = self.metrics.trace(("tick", self._seq))
             self._seq += 1
-            tr.mark("stage")
+            tr.mark("stage", sp.end)
             self._cur_trace = tr
-        mask = self._masks[i][self._fill]
-        mask[:] = False
         return self._slabs[i][self._fill], mask
 
     def commit(self, meta: Any = None) -> Optional[TickHandle]:
@@ -281,15 +295,17 @@ class PipelinedIngress:
         if not self._staged:
             raise RuntimeError("commit() without a prior stage()")
         self._staged = False
-        self._metas.append(meta)
-        if self._cur_trace is not None:
-            self._cur_trace.mark("commit")
-            self._traces.append(self._cur_trace)
-            self._cur_trace = None
-            self._m_pending.set(self._fill + 1)
-        self._fill += 1
-        if self._fill == self.window:
-            return self._dispatch()
+        with span("kws.ingress.commit", clock=self.server._clock,
+                  tick=self.server.dispatch_seq) as sp:
+            self._metas.append(meta)
+            if self._cur_trace is not None:
+                self._cur_trace.mark("commit", sp.start)
+                self._traces.append(self._cur_trace)
+                self._cur_trace = None
+                self._m_pending.set(self._fill + 1)
+            self._fill += 1
+            if self._fill == self.window:
+                return self._dispatch()
         return None
 
     def flush(self) -> Optional[TickHandle]:
@@ -315,12 +331,10 @@ class PipelinedIngress:
             )
             handle.meta = list(self._metas)
         traces, self._traces = self._traces, []
-        if traces:
-            # one device call serves the whole window: its ticks share
-            # the dispatch timestamp (and, at retire, done_at)
-            t = self.metrics.clock()
-            for tr in traces:
-                tr.mark("dispatch", t)
+        # one device call serves the whole window: its ticks share the
+        # dispatch timestamp (and, at retire, done_at)
+        for tr in traces:
+            tr.mark("dispatch", handle.dispatched_at)
         if self.metrics is not None:
             self._m_dispatches.inc()
             self._m_in_flight.set(len(self._fifo) + 1)
@@ -334,9 +348,8 @@ class PipelinedIngress:
     def _retire(self, h: TickHandle, traces) -> None:
         """Force one in-flight dispatch to completion and collect it."""
         h.result()
-        if traces:
-            for tr in traces:
-                tr.mark("retire", h.done_at)
+        for tr in traces:
+            tr.mark("retire", h.done_at)
         if self.metrics is not None:
             self._m_in_flight.set(len(self._fifo))
         self._retired.append(h)

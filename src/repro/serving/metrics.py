@@ -18,12 +18,30 @@ module is the one process-local home for all of it:
     the gap. `StreamingKWSServer` journals compiles / retraces /
     resizes / shard losses here, the `Autoscaler` every capacity
     decision with its reason.
+  * `span` — the serving path's one timing primitive: a named host
+    interval written into the profiler's trace
+    (`jax.profiler.TraceAnnotation`, free when no profiler runs), with
+    its own start/end clock reads and, when given a registry
+    `Histogram`, the same interval observed into it — a histogram and
+    its span can never time different intervals. Every span of one
+    device dispatch carries ``tick=<the server's dispatch number>`` as
+    trace metadata; the number is never part of the name.
   * `TickTrace` — per-tick span timestamps: named marks ("stage",
-    "commit", "dispatch", "retire") recorded by the async ingress as a
-    tick moves through the pipeline. Completed traces live in a bounded
-    ring (`registry.traces`); `span_percentiles` rolls consecutive-mark
-    durations into p50/p99 summaries (the numbers
-    `benchmarks/serve_load.py` records per pipelined row).
+    "commit", "dispatch", "retire") the async ingress takes from the
+    boundaries of its spans as a tick moves through the pipeline.
+    Completed traces live in a bounded ring (`registry.traces`);
+    `span_percentiles` rolls consecutive-mark durations into p50/p99
+    summaries (the numbers `benchmarks/serve_load.py` records per
+    pipelined row).
+
+The spans, a contract once a benchmark reads them (PERF.md names the
+metric each feeds): ``kws.ingress.stage`` (child
+``kws.ingress.reuse_wait``, only when a staging buffer's previous tick
+had to be forced), ``kws.ingress.commit`` > ``kws.server.dispatch`` >
+``kws.server.tick_call``, ``kws.server.own_copy`` (both under
+``kws.server.compile`` on a dispatch that traces a new program or
+shape), ``kws.server.step`` (synchronous `step_batch`), and
+``kws.handle.fetch`` > ``kws.handle.wait``, ``kws.handle.d2h``.
 
 Everything is host-side Python: no device code, no forced syncs, no
 change to any tick's operands or dispatch order — which is what makes a
@@ -41,6 +59,8 @@ import collections
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "TICK_BUDGET_MS",
     "DEFAULT_MS_BUCKETS",
@@ -48,6 +68,7 @@ __all__ = [
     "Gauge",
     "Histogram",
     "EventJournal",
+    "span",
     "TickTrace",
     "MetricsRegistry",
     "span_percentiles",
@@ -195,6 +216,41 @@ class EventJournal:
 
     def snapshot(self) -> List[Dict[str, Any]]:
         return [dict(ev) for ev in self.events]
+
+
+class span:
+    """``with span(name, hist, clock, **meta) as sp:`` — one named host
+    interval, on the profiler's clock and on ``clock``.
+
+    Always enters ``TraceAnnotation(name, **meta)`` (which costs about
+    a microsecond and records nothing unless a profiler is running),
+    reads ``clock`` on entry and exit into ``sp.start`` / ``sp.end``,
+    and observes the interval in milliseconds into ``hist`` when one is
+    given. Callers take trace marks and completion stamps from
+    ``start`` / ``end`` rather than reading the clock again.
+    """
+
+    __slots__ = ("_ann", "_hist", "_clock", "start", "end")
+
+    def __init__(self, name: str, hist: Optional[Histogram] = None,
+                 clock: Callable[[], float] = time.perf_counter,
+                 **meta: Any):
+        self._ann = TraceAnnotation(name, **meta)
+        self._hist = hist
+        self._clock = clock
+        self.start: Optional[float] = None
+        self.end: Optional[float] = None
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.start = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = self._clock()
+        self._ann.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe((self.end - self.start) * 1e3)
 
 
 class TickTrace:

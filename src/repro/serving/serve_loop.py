@@ -62,8 +62,10 @@ pre-sharding single-device program.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -96,7 +98,7 @@ from repro.distributed.sharding import (
 from repro.models.registry import get_backbone
 from repro.serving.autoscale import StreamRouter
 from repro.serving.ingress import TickHandle
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import MetricsRegistry, span
 
 Pytree = Any
 
@@ -315,6 +317,12 @@ def _fused_tick(pipeline, raw_audio, params, state: ServerState, inp,
     )
 
 
+def _own_copies(scores, top):
+    """See `_own`; named, so that its device program reads
+    ``jit__own_copies`` in a profile."""
+    return jnp.copy(scores), jnp.copy(top)
+
+
 def _reset_slot(state: ServerState, slot) -> ServerState:
     """Zero one slot's slice of every state buffer (slot is traced, so
     open/close never recompiles). The zero is written in each leaf's
@@ -391,6 +399,9 @@ class StreamingKWSServer:
     — exactly the ticks that pay jax's trace+compile cost, e.g. the
     first tick after a `resize` to a not-yet-seen capacity (resizing
     BACK to a seen capacity hits jax's cache and counts nothing).
+    Every dispatch also writes ``kws.server.*`` spans into the
+    profiler's trace (`repro.serving.metrics.span`; free when no
+    profiler runs), tagged ``tick=srv.dispatch_seq`` at dispatch.
     """
 
     def __init__(self, pipeline, params, max_streams: int = 256,
@@ -513,6 +524,8 @@ class StreamingKWSServer:
         self._retraces = 0
         self._compiles = 0
         self._tick_shapes: set = set()
+        # device dispatches so far: the next one's ``tick`` number
+        self.dispatch_seq = 0
         # metrics: True -> fresh default registry, an existing
         # MetricsRegistry -> shared, any falsy value (None/False) -> off
         if metrics is True:
@@ -520,6 +533,8 @@ class StreamingKWSServer:
         elif not metrics:
             metrics = None
         self.metrics: Optional[MetricsRegistry] = metrics
+        self._clock = time.perf_counter if metrics is None else metrics.clock
+        self._m_dispatch = self._m_fetch = self._m_tick = None
         if metrics is not None:
             self._m_ticks = metrics.counter(
                 "kws_serve_ticks_total",
@@ -652,7 +667,7 @@ class StreamingKWSServer:
         # asynchronously right behind the tick, so a TickHandle stays
         # valid however late it is fetched. Shardings are inherited
         # from the inputs, so the same program serves the mesh path.
-        self._own = jax.jit(lambda s, t: (jnp.copy(s), jnp.copy(t)))
+        self._own = jax.jit(_own_copies)
 
     # ---- observability ----
 
@@ -676,13 +691,14 @@ class StreamingKWSServer:
         mesh change, i.e. `recover_shard_loss`)."""
         return self._compiles
 
-    def _note_dispatch(self, program: str, shape) -> None:
-        """Record one dispatch of `program` at `shape`: the first
-        (program, shape) since the last `_compile_programs` is a
-        retrace (jax traces+compiles under this very call)."""
+    def _note_dispatch(self, program: str, shape) -> bool:
+        """Record one dispatch of `program` at `shape`; True when it is
+        a retrace: the first (program, shape) since the last
+        `_compile_programs` (jax traces+compiles under this very
+        call)."""
         key = (program, tuple(int(d) for d in shape))
         if key in self._tick_shapes:
-            return
+            return False
         self._tick_shapes.add(key)
         self._retraces += 1
         if self.metrics is not None:
@@ -691,6 +707,7 @@ class StreamingKWSServer:
                 "retrace", program=program, shape=list(key[1]),
                 max_streams=self.max_streams,
             )
+        return True
 
     def _update_occupancy_gauges(self) -> None:
         if self.metrics is None:
@@ -1137,13 +1154,9 @@ class StreamingKWSServer:
         The arrays are OWNED copies (never views of donation-bound
         buffers): this is `step_batch_async` fetched immediately.
         """
-        m = self.metrics
-        if m is None:
+        with span("kws.server.step", self._m_tick, self._clock,
+                  tick=self.dispatch_seq):
             return self.step_batch_async(slab, mask).result()
-        t0 = m.clock()
-        out = self.step_batch_async(slab, mask).result()
-        self._m_tick.observe((m.clock() - t0) * 1e3)
-        return out
 
     def step_batch_async(self, slab, mask) -> TickHandle:
         """Non-blocking tick: dispatch and return a deferred handle.
@@ -1171,29 +1184,9 @@ class StreamingKWSServer:
         a single-core host, most of the live-vs-scan dispatch gap.
         """
         raw = self._is_raw(int(np.shape(slab)[-1]))
-        tick = self._tick_audio if raw else self._tick_fv
-        self._note_dispatch(
-            "tick_audio" if raw else "tick_fv", np.shape(slab)
-        )
-        m = self.metrics
-        if m is None:
-            self.state, scores, top = tick(
-                self.params, self.state, slab, mask,
-                self.frontend_state, self.smoothing,
-            )
-            return TickHandle(*self._own(scores, top))
-        t0 = m.clock()
-        self.state, scores, top = tick(
-            self.params, self.state, slab, mask,
-            self.frontend_state, self.smoothing,
-        )
-        handle = TickHandle(
-            *self._own(scores, top), fetch_hist=self._m_fetch,
-            clock=m.clock,
-        )
-        self._m_ticks.inc()
-        self._m_dispatch.observe((m.clock() - t0) * 1e3)
-        return handle
+        fn = self._tick_audio if raw else self._tick_fv
+        return self._dispatch(fn, "tick_audio" if raw else "tick_fv",
+                              slab, mask, 1)
 
     def step(self, frames: Dict[int, np.ndarray]) -> Dict[int, dict]:
         """frames: stream_id -> FV_Norm (C,) or raw audio hop (S,).
@@ -1247,29 +1240,33 @@ class StreamingKWSServer:
         `step_batch_async`.
         """
         raw = self._is_raw(int(np.shape(slab)[-1]))
-        run = self._run_audio if raw else self._run_fv
-        self._note_dispatch(
-            "run_audio" if raw else "run_fv", np.shape(slab)
-        )
-        m = self.metrics
-        if m is None:
-            self.state, scores_seq, tops = run(
-                self.params, self.state, slab, mask,
-                self.frontend_state, self.smoothing,
-            )
-            return TickHandle(*self._own(scores_seq, tops))
-        t0 = m.clock()
-        self.state, scores_seq, tops = run(
-            self.params, self.state, slab, mask,
-            self.frontend_state, self.smoothing,
-        )
-        handle = TickHandle(
-            *self._own(scores_seq, tops), fetch_hist=self._m_fetch,
-            clock=m.clock,
-        )
-        self._m_ticks.inc(int(np.shape(slab)[0]))
-        self._m_dispatch.observe((m.clock() - t0) * 1e3)
-        return handle
+        fn = self._run_audio if raw else self._run_fv
+        return self._dispatch(fn, "run_audio" if raw else "run_fv",
+                              slab, mask, int(np.shape(slab)[0]))
+
+    def _dispatch(self, fn, program: str, slab, mask,
+                  n_ticks: int) -> TickHandle:
+        """Enqueue one device call of `fn` (a tick or a scanned window)
+        and the owned copies of its outputs, under the dispatch spans;
+        returns the handle, which carries the dispatch's number."""
+        k = self.dispatch_seq
+        self.dispatch_seq += 1
+        compiles = self._note_dispatch(program, np.shape(slab))
+        with span("kws.server.dispatch", self._m_dispatch, self._clock,
+                  tick=k) as sp:
+            with (span("kws.server.compile", tick=k) if compiles
+                  else contextlib.nullcontext()):
+                with span("kws.server.tick_call", tick=k):
+                    self.state, scores, top = fn(
+                        self.params, self.state, slab, mask,
+                        self.frontend_state, self.smoothing,
+                    )
+                with span("kws.server.own_copy", tick=k):
+                    scores, top = self._own(scores, top)
+        if self.metrics is not None:
+            self._m_ticks.inc(n_ticks)
+        return TickHandle(scores, top, tick=k, dispatched_at=sp.end,
+                          fetch_hist=self._m_fetch, clock=self._clock)
 
     def run(self, buffers: Dict[int, np.ndarray]) -> Dict[int, dict]:
         """Offline replay: buffered audio -> per-tick posteriors, scanned.

@@ -21,6 +21,7 @@ the persistent compilation cache is off while these compiles run.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -146,6 +147,29 @@ def test_auto_tick_compiles_for_v5e(one_chip, norm_stats, params,
     n_gemm = 5 if classifier in ("integer", "delta-int") else 0
     assert _kernels(compiled) == n_gemm  # 2 layers x (W_i, W_h) + FC
     assert _kernels(scanned) == n_gemm
+
+
+def test_tick_ops_carry_stable_names_on_v5e(one_chip, norm_stats, params):
+    """The names a profile of the integer tick is read by: the five GEMM
+    kernels are instructions named ``kws_intgemm`` (holding the
+    ``intgemm`` the roofline reduction matches by), and each layer's
+    three ROM gathers carry its ``kws_gru{l}_gates`` scope."""
+    srv = StreamingKWSServer(_pipe(norm_stats, "integer"), params,
+                             max_streams=N_STREAMS)
+    with force_dispatch("pallas"):
+        text = srv._tick_fv.lower(
+            *_operands(srv, False, one_chip)
+        ).compile().as_text()
+    entry = text[text.index("\nENTRY"):].splitlines()
+    kernels = [ln.split()[0] for ln in entry
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 5
+    assert all(k.startswith("%kws_intgemm") for k in kernels)
+    gathers = re.findall(
+        r'op_name="[^"]*/(kws_gru\d_gates)/jit\(_take\)/gather"',
+        "\n".join(ln for ln in entry if " fusion(" in ln),
+    )
+    assert sorted(gathers) == ["kws_gru0_gates"] * 3 + ["kws_gru1_gates"] * 3
 
 
 def test_cascaded_auto_tick_compiles_for_v5e(one_chip, norm_stats, params):
