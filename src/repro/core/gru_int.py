@@ -11,7 +11,9 @@ way the chip's 8 HPEs do (Sections II, III-E):
   * matmuls through `repro.kernels.intgemm` (24-bit saturating
     accumulator; Pallas on TPU, exact jnp reference elsewhere),
   * sigmoid/tanh as Q6.8 ROM lookups (`quant.lut_sigmoid_q68` /
-    `quant.lut_tanh_q68`) over the 15-bit summed-preactivation domain,
+    `quant.lut_tanh_q68`) over the 15-bit summed-preactivation domain;
+    the ROMs are built on the host CPU, and the device reads each
+    as the thresholds of its unit steps (a compare-and-count, no gather),
   * every rescale a single round-to-nearest-even shift
     (`quant.round_shift_even`) plus Q6.8 saturation.
 
@@ -116,7 +118,7 @@ def int_gru_cell(
     """One GRU step on codes: x (B, I), h (B, H) -> h' (B, H), int32.
 
     Its stages run under named scopes ``<scope>_gemm`` (the input and
-    recurrent accumulations), ``<scope>_gates`` (the three ROM lookups)
+    recurrent accumulations), ``<scope>_gates`` (the three ROM step counts)
     and ``<scope>_update`` (the state update)."""
     del config  # geometry is carried by the code arrays themselves
     with jax.named_scope(f"{scope}_gemm"):

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -168,7 +168,8 @@ def log_compress_lut(codes: jnp.ndarray, in_bits: int = 12, out_bits: int = 10):
 # tested in tests/test_classifier_int.py). Rescaling a frac-a x frac-b
 # product (or a bias-augmented accumulator) back to Q6.8 is a single
 # `round_shift_even`; sigmoid/tanh are ROM lookups over the 15-bit sum
-# of two saturated Q6.8 addends, exactly as the IC's LUTs.
+# of two saturated Q6.8 addends, exactly as the IC's LUTs (read on the
+# device as a count of the ROM's unit steps, `lut_sigmoid_q68`).
 # --------------------------------------------------------------------------
 
 def round_shift_even(codes: jnp.ndarray, shift: int) -> jnp.ndarray:
@@ -210,7 +211,8 @@ def _host_rom(fn) -> np.ndarray:
     v5e), enough to flip a Q6.8 rounding. A ROM is one table, so it is
     built on one backend: the integer engine and the QAT gates
     (`rom_sigmoid`, `rom_tanh`) then serve the same codes on every
-    platform.
+    platform. The devices never index the table: they read it as the
+    thresholds of its unit steps (`_rom_steps`), also taken on the host.
     Built eagerly even when first requested under a trace (the cached
     table must be a constant, not a tracer of the enclosing scan/jit).
     """
@@ -247,16 +249,68 @@ def tanh_lut_q68() -> np.ndarray:
     return _host_rom(jnp.tanh)
 
 
+def _rom_steps(table: np.ndarray) -> Tuple[int, np.ndarray]:
+    """``(base, thresholds)`` of a ROM over the LUT domain that climbs in
+    unit steps: ``table[c - _LUT_MIN] == base + #{k : c >= thresholds[k]}``.
+
+    ``base`` is the first entry and ``thresholds`` are the int32 codes at
+    which the table steps up by one. Raises ValueError when a step
+    between neighbouring entries is anything but 0 or 1, so a ROM that
+    loses the property fails when it is read, not in a lookup.
+    """
+    steps = np.diff(np.asarray(table, np.int64))
+    bad = np.flatnonzero((steps != 0) & (steps != 1))
+    if bad.size:
+        i = int(bad[0])
+        raise ValueError(
+            f"the ROM steps by {steps[i]} at code {i + 1 + _LUT_MIN}; a "
+            "threshold count needs every step to be 0 or 1"
+        )
+    thresholds = np.flatnonzero(steps) + 1 + _LUT_MIN
+    return int(table[0]), thresholds.astype(np.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _sigmoid_steps() -> Tuple[int, np.ndarray]:
+    return _rom_steps(sigmoid_lut_q68())
+
+
+@functools.lru_cache(maxsize=None)
+def _tanh_steps() -> Tuple[int, np.ndarray]:
+    return _rom_steps(tanh_lut_q68())
+
+
+def _count_steps(codes: jnp.ndarray, base: int,
+                 thresholds: np.ndarray) -> jnp.ndarray:
+    """``base + #{k : codes >= thresholds[k]}`` elementwise, int32.
+
+    Equal to the ROM indexed at ``clip(codes, _LUT_MIN, _LUT_MAX)`` for
+    every int32 code (below every threshold is the first entry, above
+    all of them the last), with no gather: one fused compare-and-sum
+    per element, plain vector work on every backend. The thresholds run
+    along the leading axis, so the sum accumulates element-wise, and the
+    codes are laid out 128 to a row where their count allows: a TPU pads
+    the 48 lanes of a (streams, 48) gate to 128 (on a v5e this form took
+    0.107 ms per layer at 3072 streams against 0.408 ms with the
+    thresholds on the minor axis of the unflattened gate).
+    """
+    shape = codes.shape
+    if codes.size % 128 == 0:
+        codes = codes.reshape(-1, 128)
+    hits = codes >= thresholds.reshape((-1,) + (1,) * codes.ndim)
+    return (base + jnp.sum(hits, axis=0, dtype=jnp.int32)).reshape(shape)
+
+
 def lut_sigmoid_q68(codes: jnp.ndarray) -> jnp.ndarray:
-    """Integer sigmoid: summed Q6.8 preactivation codes -> Q6.8 codes."""
-    idx = jnp.clip(codes, _LUT_MIN, _LUT_MAX) - _LUT_MIN
-    return jnp.take(sigmoid_lut_q68(), idx)
+    """Integer sigmoid: summed Q6.8 preactivation codes -> Q6.8 codes,
+    read from `sigmoid_lut_q68` as its 256 unit steps."""
+    return _count_steps(codes, *_sigmoid_steps())
 
 
 def lut_tanh_q68(codes: jnp.ndarray) -> jnp.ndarray:
-    """Integer tanh: summed Q6.8 preactivation codes -> Q6.8 codes."""
-    idx = jnp.clip(codes, _LUT_MIN, _LUT_MAX) - _LUT_MIN
-    return jnp.take(tanh_lut_q68(), idx)
+    """Integer tanh: summed Q6.8 preactivation codes -> Q6.8 codes,
+    read from `tanh_lut_q68` as its 512 unit steps."""
+    return _count_steps(codes, *_tanh_steps())
 
 
 def _rom_act(fn, lut):

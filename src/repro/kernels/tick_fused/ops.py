@@ -75,12 +75,12 @@ def mosaic_unsupported(pipeline, raw_audio: bool) -> Optional[str]:
             "truncation, which Mosaic does not lower"
         )
     name = pipeline.config.classifier_key
-    if name in ("qat", "integer", "delta-int"):
+    if name in ("qat", "integer"):
         return (
-            f"the {name!r} backend's sigmoid/tanh ROM lookup is a 1-D "
-            "gather (jnp.take), and Mosaic lowers only 2-D gathers"
+            f"the {name!r} backend's sigmoid/tanh step count lays each "
+            "gate's codes 128 to a row, a shape cast Mosaic does not lower"
         )
-    if name == "delta":
+    if name in ("delta", "delta-int"):
         return (
             "the ΔGRU gather path compacts firing columns with cumsum, "
             "which Mosaic does not lower"

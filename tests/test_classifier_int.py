@@ -186,6 +186,42 @@ def test_lut_nonlinearities_match_fake_quant():
     )
 
 
+@pytest.mark.parametrize("layout", ["gates", "ragged"])
+@pytest.mark.parametrize("name", ["sigmoid", "tanh"])
+def test_lut_step_count_equals_clipped_rom(name, layout):
+    """The device form (a count of the ROM's unit steps) returns the ROM
+    entry at the clipped code, for every code within 64 of the domain
+    and at the int32 extremes: laid out as (streams, 48) gates, which
+    the count takes 128 to a row, and as a vector it cannot."""
+    lut, table = {
+        "sigmoid": (quant.lut_sigmoid_q68, quant.sigmoid_lut_q68()),
+        "tanh": (quant.lut_tanh_q68, quant.tanh_lut_q68()),
+    }[name]
+    info = np.iinfo(np.int32)
+    codes = np.arange(quant._LUT_MIN - 64, quant._LUT_MAX + 65)
+    extremes = np.resize([info.min, info.min + 1, info.max - 1, info.max],
+                         -codes.size % 384)  # 384: rows of 48 and of 128
+    codes = np.concatenate([codes, extremes]).astype(np.int32)
+    codes = codes.reshape(-1, 48) if layout == "gates" else codes[:-1]
+    idx = np.clip(codes, quant._LUT_MIN, quant._LUT_MAX) - quant._LUT_MIN
+    got = np.asarray(lut(jnp.asarray(codes)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, table[idx])
+
+
+@pytest.mark.parametrize("bad,step,at", [
+    ([0, 0, 2, 3], 2, 2),
+    ([0, 1, 2, 1], -1, 3),
+], ids=["step-of-2", "step-down"])
+def test_rom_steps_refuses_non_unit_steps(bad, step, at):
+    base, thresholds = quant._rom_steps(np.array([3, 3, 4, 5, 5], np.int32))
+    assert base == 3
+    np.testing.assert_array_equal(thresholds, quant._LUT_MIN + np.array([2, 3]))
+    with pytest.raises(ValueError, match=f"steps by {step} at code "
+                                         f"{quant._LUT_MIN + at}"):
+        quant._rom_steps(np.array(bad, np.int32))
+
+
 @settings(max_examples=30, deadline=None)
 @given(
     v=st.integers(min_value=-(2**23), max_value=2**23 - 1),

@@ -144,17 +144,19 @@ def test_tick_impl_validation_and_resolution(norm_stats, shared_params):
 
 
 @pytest.mark.parametrize("classifier,cascade,match", [
-    ("qat", None, "gather"),
-    ("integer", None, "gather"),
-    ("delta-int", None, "gather"),
+    ("qat", None, "shape cast"),
+    ("integer", None, "shape cast"),
+    ("delta-int", None, "cumsum"),
     ("delta", None, "cumsum"),
     ("qat", CascadeConfig(), "truncation"),
-])
+], ids=["qat-None-gather", "integer-None-gather", "delta-int-None-gather",
+        "delta-None-cumsum", "qat-cascade4-truncation"])
 def test_fused_pallas_rejects_what_mosaic_cannot_lower(
     norm_stats, shared_params, classifier, cascade, match
 ):
     """The compiled megakernel is refused, naming the primitive, at
-    construction — before anything is traced or compiled."""
+    construction — before anything is traced or compiled. (The ids keep
+    the names the cases had when the ROM lookup was a gather.)"""
     with pytest.raises(ValueError, match=match):
         StreamingKWSServer(
             _pipe(norm_stats, classifier, cascade), shared_params,
