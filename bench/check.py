@@ -16,18 +16,29 @@ is held to the limit the configuration file gives it:
                     where the reference's top-1 leads by 1e-4 or more.
   det_mismatch      (cascade) share of streams whose gate state (latch,
                     hangover, woken and tick counters) differs at the end.
+  carry_err         (raw-audio mixes) the largest absolute difference,
+                    over the sampled streams, their channels and both
+                    registers of each biquad, between the server's
+                    frontend filter state at the end and the reference's;
+                    a state that is not finite on either side reads inf.
+                    A configuration without a limit for it fails every
+                    raw-audio run (`verdict`).
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
 SCORE_TOL = 1e-4
 
 
-def numbers(served: dict, ref, missing_hops: int) -> Dict[str, float]:
+def numbers(served: dict, ref, missing_hops: int,
+            carry: Optional[dict] = None) -> Dict[str, float]:
+    """The check's numbers. ``carry`` is the reference frontend's final
+    filter state where the streams sent raw audio; ``served["carry"]``
+    then holds the server's at the sampled streams."""
     out = {"missing_hops": float(missing_hops)}
     for i, (hp, hr) in enumerate(zip(served["h"], ref.h)):
         out[f"h{i}_mismatch"] = float(np.mean(hp != hr))
@@ -42,6 +53,12 @@ def numbers(served: dict, ref, missing_hops: int) -> Dict[str, float]:
         for key, val in ref.det.items():
             differs |= np.asarray(served["det"][key]).astype(np.int64) != val
         out["det_mismatch"] = float(np.mean(differs))
+    if carry is not None:
+        gaps = [np.abs(np.asarray(served["carry"][key], np.float64)
+                       - np.asarray(val, np.float64))
+                for key, val in carry.items()]
+        out["carry_err"] = max(float(np.max(np.nan_to_num(g, nan=np.inf)))
+                               for g in gaps)
     return out
 
 

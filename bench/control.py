@@ -4,13 +4,16 @@ server's place.
     python3 -m bench.control --workload int8-fv-rt --seeds 1 2 3 --seconds 20
 
 For each seed it builds the cell's traffic, weights and norm statistics
-exactly as a run does, serves the sampled streams' frames of an
-open-loop window through the reference one precision below the
-configuration's, the classifier with int4 weights (the int8 codes
-rounded to multiples of 16) instead of int8, and compares that with the
-reference by the run's own numbers. Each line printed is one seed's
-readings; a sound check fails every one of them. Needs no chip, and
-runs the frontend that makes the frames on whatever device JAX offers.
+exactly as a run does, replays the sampled streams over an open-loop
+window through the configuration's reference one precision below what
+the configuration states, and compares that with the reference by the
+run's own numbers. Where the streams upload FV_Norm frames the step down
+is the classifier's: int4 weights (the int8 codes rounded to multiples
+of 16) instead of int8. Where they send raw audio it is the frontend's:
+the filter in bfloat16 instead of float32, before the int8 classifier.
+Each line printed is one seed's readings; a sound check fails every one
+of them. Needs no chip, and runs the reference frontend on whatever
+device JAX offers.
 """
 
 from __future__ import annotations
@@ -33,10 +36,11 @@ def int4(codes: dict) -> dict:
 
 def readings(workload: str, seed: int, seconds: float,
              mix_override: dict | None = None) -> dict:
-    from bench import check, harness, model, reference, traffic as tl
+    from bench import check, harness, model, traffic as tl
 
     _, cell = harness.spec(workload)
     cfg = model.load(cell["config"])
+    ref = harness.reference_module(cfg)
     mix = dict(tl.load(cell["traffic"]), **(mix_override or {}))
     seed = int(seed) % 2 ** 63
     codes, _ = model.weights(cfg, seed)
@@ -45,13 +49,19 @@ def readings(workload: str, seed: int, seconds: float,
     sample = harness.sample_streams(mix["streams"], mix["check_streams"],
                                     seed)
     n_ticks = int(round(seconds * 1000.0 / mix["hop_ms"]))
-    traffic.pool = harness.fv_pool(cfg, norm, traffic)
-    fv = harness.sample_codes(traffic, sample, n_ticks)
-    ref = reference.classifier(cfg, codes, fv)
-    ctl = reference.classifier(cfg, int4(codes), fv)
+    audio = harness.input_kind(mix) == "audio"
+    if not audio:
+        traffic.pool = harness.fv_pool(ref, cfg, norm, traffic)
+
+    def serve(weights, dtype="float32"):
+        return harness.replay(ref, cfg, norm, weights, traffic, mix, sample,
+                              n_ticks, dtype)
+
+    want, carry = serve(codes)
+    ctl, ctl_carry = serve(codes, "bfloat16") if audio else serve(int4(codes))
     served = {"scores": ctl.scores.astype("float32"), "top": ctl.top,
-              "h": list(ctl.h), "det": ctl.det}
-    nums = check.numbers(served, ref, 0)
+              "h": list(ctl.h), "det": ctl.det, "carry": ctl_carry}
+    nums = check.numbers(served, want, 0, carry)
     correct, _ = check.verdict(nums, cfg["limits"])
     return {"seed": seed, "correct": correct, **nums}
 

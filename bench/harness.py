@@ -1,8 +1,28 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Everything a cell needs is found by name: the cell in BENCHMARK.json,
-its configuration in ``configs/``, its traffic mix in ``traffic/`` and
-each per-layer metric's reader in ``metrics/<name>.py``.
+its configuration in ``configs/``, its traffic mix in ``traffic/``, each
+per-layer metric's reader in ``metrics/<name>.py`` and the plain
+reference in ``bench/<reference>.py``, which the configuration may name
+(default ``reference``).
+
+What a stream sends is the mix's ``input``: "fv" (the default), the
+16-channel FV_Norm frame of each hop, made by the reference's frontend
+so that the server's frontend is bypassed; or "audio", the raw hop of
+``hop_samples`` samples, so that the server runs its own frontend on the
+device (`StreamingKWSServer` picks the program by the slab's width). The
+server is built from the configuration's keys, the optional ``delta``
+(ΔGRU thresholds, `DeltaConfig`) and ``tdfex`` (the time-domain
+frontend's parameters, `TDFExConfig`) among them.
+
+A traced run profiles the window's first ``trace_ticks`` ticks and hands
+each per-layer reader one context: the configuration and mix, the trace's
+device summary (`bench.trace.summarize`), the slice's host timings and
+``spans``, the readings of the program's own spans and device scopes
+(`bench.spans.readings`, ``scope_ms`` holding every ``kws_*`` scope's
+device ms per tick), made with the tick program the window ran, compiled
+again once the window has closed. An untraced run reads no per-layer
+metric.
 
 Open loop ("open" mixes): tick k is due at t0 + k * hop_ms for every
 stream. The main thread waits for each due time, stages the tick into
@@ -17,11 +37,13 @@ from __future__ import annotations
 import contextlib
 import gc
 import glob
+import importlib
 import importlib.util
 import json
 import math
 import pathlib
 import queue
+import re
 import shutil
 import sys
 import tempfile
@@ -31,7 +53,8 @@ import types
 
 import numpy as np
 
-from bench import check, model, ops, reference
+from bench import check, model, ops
+from bench import spans as spans_lib
 from bench import trace as trace_lib
 from bench import traffic as traffic_lib
 
@@ -121,7 +144,9 @@ def build_server(cfg, floats, norm, n_streams, chips):
 
     from repro.core.fex import FExConfig, FExNormStats
     from repro.core.gru import GRUConfig
+    from repro.core.gru_delta import DeltaConfig
     from repro.core.pipeline import KWSPipeline, KWSPipelineConfig
+    from repro.core.tdfex import TDFExConfig
     from repro.serving.cascade import CascadeConfig
     from repro.serving.serve_loop import StreamingKWSServer
 
@@ -136,11 +161,13 @@ def build_server(cfg, floats, norm, n_streams, chips):
                     hidden_dim=cfg["hidden_dim"],
                     num_layers=cfg["num_layers"],
                     num_classes=cfg["num_classes"])
-    casc = cfg.get("cascade")
+    casc, delta, tdfex = (cfg.get(k) for k in ("cascade", "delta", "tdfex"))
     pcfg = KWSPipelineConfig(
         frontend=cfg["frontend"], fex=fex, gru=gru,
         classifier=cfg["classifier"],
-        cascade=None if not casc else CascadeConfig(**casc))
+        cascade=None if not casc else CascadeConfig(**casc),
+        delta=None if delta is None else DeltaConfig(**delta),
+        tdfex=None if tdfex is None else TDFExConfig(fex=fex, **tdfex))
     pipe = KWSPipeline(pcfg, norm_stats=FExNormStats(
         mu=jnp.asarray(norm["mu"]), sigma=jnp.asarray(norm["sigma"])))
     if pipe.chunk_samples != cfg["hop_samples"]:
@@ -172,12 +199,28 @@ def _readers(bench, cell, e2e_names):
     return out
 
 
-def fv_pool(cfg, norm, traffic) -> np.ndarray:
+def reference_module(cfg):
+    """The plain reference the configuration names: ``bench/<name>.py``."""
+    name = cfg.get("reference", "reference")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise ValueError(f"reference {name!r} is not a module name")
+    return importlib.import_module(f"bench.{name}")
+
+
+def input_kind(mix) -> str:
+    """What each stream sends: "fv" frames (the default) or "audio"."""
+    kind = mix.get("input", "fv")
+    if kind not in ("fv", "audio"):
+        raise ValueError(f"unknown input {kind!r}")
+    return kind
+
+
+def fv_pool(ref, cfg, norm, traffic) -> np.ndarray:
     """The pool's audio as FV_Norm frames, one row per hop, made by the
-    benchmark's own frontend over each track from its start."""
+    reference's frontend over each track from its start."""
     n_tr = traffic.pool.shape[0] // traffic.n_hops
     audio = traffic.pool.reshape(n_tr, traffic.n_hops, -1)
-    codes = reference.frontend(
+    codes, _ = ref.frontend(
         cfg, norm, lambda a, b: audio[:, a:b].transpose(1, 0, 2),
         traffic.n_hops, n_tr)
     return np.ascontiguousarray(
@@ -185,10 +228,36 @@ def fv_pool(cfg, norm, traffic) -> np.ndarray:
         / np.float32(256.0), np.float32)
 
 
-def sample_codes(traffic, streams, n_ticks: int) -> np.ndarray:
-    """(n_ticks, len(streams), C) FV_Norm codes the streams uploaded."""
-    return np.stack([np.round(traffic.pool[traffic.rows(t, streams)] * 256)
-                     .astype(np.int64) for t in range(n_ticks)])
+def replay(ref, cfg, norm, weights, traffic, mix, streams, n_ticks: int,
+           dtype="float32"):
+    """What the reference serves ``streams`` over their first ``n_ticks``
+    ticks, and its frontend's final filter state (None for "fv" input).
+
+    Raw audio goes through the reference's frontend from tick 0, each
+    stream's hops read from the pool by the same `Traffic.rows` layout
+    the window staged; uploaded frames go straight to the classifier.
+    ``dtype`` is the frontend's precision (the control's bfloat16).
+    """
+    rows = [traffic.rows(t, streams) for t in range(n_ticks)]
+    if input_kind(mix) == "audio":
+        fv, carry = ref.frontend(
+            cfg, norm, lambda a, b: np.stack([traffic.pool[r]
+                                              for r in rows[a:b]]),
+            n_ticks, len(streams), dtype=dtype)
+    else:
+        fv = np.stack([np.round(traffic.pool[r] * 256).astype(np.int64)
+                       for r in rows])
+        carry = None
+    return ref.classifier(cfg, weights, fv), carry
+
+
+def tick_program(srv, slab, mask) -> dict:
+    """`spans.tick_map` of the tick program the window ran, for the slab's
+    input kind; compiled from the persistent cache after warm-up."""
+    fn = srv._tick_audio if srv._is_raw(slab.shape[-1]) else srv._tick_fv
+    lowered = fn.lower(srv.params, srv.state, slab, mask,
+                       srv.frontend_state, srv.smoothing)
+    return spans_lib.tick_map(lowered.compile().as_text())
 
 
 def find_chips(chips: int):
@@ -216,6 +285,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     bench, cell = spec(workload)
     cfg = model.load(cell["config"])
+    ref = reference_module(cfg)
     mix = dict(traffic_lib.load(cell["traffic"]), **(mix_override or {}))
     chips = cell["chips"]
     seed = int(seed) % 2 ** 63
@@ -239,9 +309,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     norm = model.norm_stats(cfg, seed)
     traffic = traffic_lib.Traffic(mix, hop, seed)
     phase("audio")
-    # the fleet uploads features: the server's frontend is bypassed
-    traffic.pool = fv_pool(cfg, norm, traffic)
-    phase("features")
+    if input_kind(mix) == "fv":
+        # the fleet uploads features: the server's frontend is bypassed
+        traffic.pool = fv_pool(ref, cfg, norm, traffic)
+        phase("features")
     dim = traffic.pool.shape[1]
     n = mix["streams"]
     srv = build_server(cfg, floats, norm, n, chips)
@@ -381,21 +452,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     served = {
         "scores": np.stack([fetcher.scores[i] for i in range(served_ticks)]),
         "top": np.stack([fetcher.top[i] for i in range(served_ticks)]),
-        "h": [np.asarray(g)[sample_slots] for g in srv.state.gru],
+        # a ΔGRU layer keeps its hidden codes as the dict's "h" leaf
+        "h": [np.asarray(g["h"] if isinstance(g, dict) else g)[sample_slots]
+              for g in srv.state.gru],
         "det": ({key: np.asarray(v)[sample_slots]
                  for key, v in srv.state.det.items()}
                 if srv.state.det is not None else {}),
+        "carry": {key: np.asarray(v)[sample_slots]
+                  for key, v in srv.state.carry.items()},
     }
     if srv.state.det is not None:
         log(f"measured wake share: {float(np.mean(srv.wake_rate))*100:.2f}%")
+    tick = tick_program(srv, slab, mask) if trace else None
     del srv, ingress, handle, slab, mask
     gc.collect()
 
     # ---- the check against the plain reference
     t_ref = clock()
-    fv = sample_codes(traffic, sample, served_ticks)
-    ref = reference.classifier(cfg, codes, fv)
-    nums = check.numbers(served, ref, missing)
+    want, carry = replay(ref, cfg, norm, codes, traffic, mix, sample,
+                         served_ticks)
+    nums = check.numbers(served, want, missing, carry)
     correct, rows = check.verdict(nums, cfg["limits"])
     log(f"reference: {clock() - t_ref:.2f} s over {served_ticks} ticks x "
         f"{len(sample)} streams")
@@ -407,19 +483,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         t_tr = clock()
         tr = trace_lib.from_xplane(path)
         summary = trace_lib.summarize(tr, KERNEL)
+        sl = range(min(slice_ticks, served_ticks))
+        lo, hi = trace_lib.slice_bounds(tr)
+        program = spans_lib.readings(spans_lib.from_xplane(path, tick),
+                                     lo, hi, len(sl))
         log(f"trace: {pathlib.Path(path).stat().st_size} bytes, reduced in "
             f"{clock() - t_tr:.2f} s")
         if keep_trace:
             trace_lib.to_json(tr, keep_trace)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        sl = range(min(slice_ticks, served_ticks))
         ctx = types.SimpleNamespace(
             cfg=cfg, mix=mix, chips=chips, streams=n,
             peaks=ops.peaks(kind),
             summary=summary, slice_ticks=len(sl),
             commit_s=[commit_s[i] for i in sl],
             tick_s=[done[i] - dispatched_at[i] for i in sl],
-            window_s=seconds, hops_in_window=n * in_window)
+            window_s=seconds, hops_in_window=n * in_window,
+            spans=program)
         e2e_names = [m["name"] for m in bench["end_to_end"]
                      if cell["name"] in m.get("workloads", [cell["name"]])]
         for name, unit, read in _readers(bench, cell, e2e_names):

@@ -20,9 +20,14 @@ produced for those streams:
   head        softmax of the logits and exponential smoothing of the
               posteriors, top-1 of the smoothed posterior.
 
-The frontend, which makes the FV_Norm frames the fleet uploads, runs in
-jax on the default device in float32. The classifier runs on the host in
-numpy, exact on integers.
+The frontend, which makes the FV_Norm frames a feature-upload fleet
+sends and replays a raw-audio fleet's streams, runs in jax on the
+default device, in float32 unless the control asks for one precision
+down. The classifier runs on the host in numpy, exact on integers.
+
+A configuration may name another module in ``bench/`` as its reference
+(key ``reference``); it exposes `frontend` and `classifier` with these
+signatures.
 """
 
 from __future__ import annotations
@@ -86,7 +91,9 @@ def _rshift(x, s):
 def _frontend_block(carry, hops, valid, coeffs, table, mu, sigma,
                     full_scale, levels):
     """hops (T, S, hop) -> (carry, FV_Norm codes (T, S, C) int32); a tick
-    whose ``valid`` is False leaves the carry as it was."""
+    whose ``valid`` is False leaves the carry as it was. The filter runs
+    in the dtype of ``hops``, ``coeffs`` and ``carry``; the quantizer,
+    the log table and the normalizer in float32."""
     b0, b1, b2, a1, a2 = (coeffs[i] for i in range(5))
 
     def tick(carry, xs):
@@ -105,7 +112,7 @@ def _frontend_block(carry, hops, valid, coeffs, table, mu, sigma,
         acc0 = jnp.zeros_like(carry[0])
         (s1, s2, acc), _ = jax.lax.scan(
             sample, (carry[0], carry[1], acc0), x.T)
-        frame = acc / x.shape[1]
+        frame = (acc / x.shape[1]).astype(jnp.float32)
         raw = jnp.round(jnp.clip(frame, 0.0, full_scale) / full_scale * levels)
         logv = table[raw.astype(jnp.int32)]
         norm = (logv - mu) / sigma
@@ -117,19 +124,24 @@ def _frontend_block(carry, hops, valid, coeffs, table, mu, sigma,
     return jax.lax.scan(tick, carry, (hops, valid))
 
 
-def frontend(cfg, norm, hops_fn, n_ticks, n_streams, block=128):
-    """FV_Norm codes (T, S, C), as numpy.
+def frontend(cfg, norm, hops_fn, n_ticks, n_streams, block=128,
+             dtype="float32"):
+    """(FV_Norm codes (T, S, C), final filter carry), as numpy.
 
     ``hops_fn(t0, t1)`` returns the (t1 - t0, S, hop) float32 audio of
     ticks t0..t1-1; the frontend runs over it in blocks of ``block``
     ticks so that the audio of a long run never sits on the device whole.
+    The carry is each biquad's two transposed-direct-form-II registers
+    after the last tick, ``{"s1": (S, C), "s2": (S, C)}`` in float32.
+    ``dtype`` is the filter's precision: float32, or bfloat16 for the
+    check's control.
     """
     c = cfg["num_channels"]
-    coeffs = jnp.asarray(filterbank(cfg))
+    coeffs = jnp.asarray(filterbank(cfg), dtype)
     table = jnp.asarray(log_table(cfg), jnp.float32)
     mu = jnp.asarray(norm["mu"], jnp.float32)
     sigma = jnp.asarray(norm["sigma"], jnp.float32)
-    carry = (jnp.zeros((n_streams, c), jnp.float32),) * 2
+    carry = (jnp.zeros((n_streams, c), dtype),) * 2
     out = []
     for t0 in range(0, n_ticks, block):
         t1 = min(n_ticks, t0 + block)
@@ -140,11 +152,13 @@ def frontend(cfg, norm, hops_fn, n_ticks, n_streams, block=128):
                                 np.float32)])
         valid = np.arange(block) < t1 - t0
         carry, codes = _frontend_block(
-            carry, jnp.asarray(hops), jnp.asarray(valid), coeffs, table, mu,
-            sigma, np.float32(cfg["quant_full_scale"]),
+            carry, jnp.asarray(hops, dtype), jnp.asarray(valid), coeffs,
+            table, mu, sigma, np.float32(cfg["quant_full_scale"]),
             np.float32(2 ** cfg["quant_bits"] - 1))
         out.append(np.asarray(codes))
-    return np.concatenate(out)[:n_ticks]
+    final = {"s1": np.asarray(carry[0], np.float32),
+             "s2": np.asarray(carry[1], np.float32)}
+    return np.concatenate(out)[:n_ticks], final
 
 
 # ---------------------------------------------------------------- classifier

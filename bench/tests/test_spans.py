@@ -5,14 +5,17 @@ host profile recorded on the CPU, and on a slice recorded on a TPU v5e
 tick, cut to the traced slice and written as gzipped JSON)."""
 
 import glob
+import importlib.util
 import math
 import pathlib
+import types
 
 import pytest
 
 from bench import spans, trace
 
 FIXTURES = pathlib.Path(__file__).parent / "fixtures"
+METRICS = pathlib.Path(__file__).resolve().parents[1] / "metrics"
 TICK, OWN = "jit__tick", "jit__own_copies"
 
 
@@ -202,3 +205,59 @@ def test_the_existing_reduction_reads_as_before():
     assert s["kernel_n"] == {"/device:TPU:0": 30}
     assert s["device_ops"][0] == ("%fusion.10", pytest.approx(0.002105403))
     assert s["idle_by_phase"]["wait"] == pytest.approx(0.065403265)
+
+
+# ---- the per-layer readers of these readings (bench/metrics/)
+
+READERS = ["gate_rom_ms.rt", "gate_rom_ms.drain", "tick_call_ms.rt",
+           "tick_call_ms.drain", "return_ms.rt"]
+
+
+def reader(name):
+    sp = importlib.util.spec_from_file_location(
+        "reader_" + name.replace(".", "_"), METRICS / f"{name}.py")
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod.read
+
+
+def expected(name, r):
+    if name == "return_ms.rt":  # the two lags' clock offsets cancel
+        return r["launch_lag_ms"] + r["scores_lag_ms"]
+    return r[name.split(".")[0]]
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_readers_of_a_hand_made_trace(name):
+    r = spans.readings(hand_trace(), 0, 160, ticks=2)
+    got = reader(name)(types.SimpleNamespace(spans=r))
+    assert got == pytest.approx(expected(name, r))
+    assert got > 0
+
+
+def test_return_ms_of_a_hand_made_trace():
+    r = spans.readings(hand_trace(), 0, 160, ticks=2)
+    # launch lags 5 and 7 ns, scores lags 2 and 9 ns: means 6 + 5.5 ns
+    assert reader("return_ms.rt")(types.SimpleNamespace(spans=r)) == (
+        pytest.approx(11.5e-6))
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_readers_of_the_recorded_slice(name, chip_slice):
+    r = spans.readings(chip_slice, 0, math.inf, ticks=64)
+    got = reader(name)(types.SimpleNamespace(spans=r))
+    assert got == pytest.approx(expected(name, r))
+    assert got > 0
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_readers_read_nothing_without_spans(name):
+    """An untraced context, or one from a checkout whose harness hands no
+    span readings, gives no value."""
+    assert reader(name)(types.SimpleNamespace(summary={})) is None
+
+
+@pytest.mark.parametrize("lag", ["launch_lag_ms", "scores_lag_ms"])
+def test_return_ms_needs_both_lags(lag):
+    r = dict(spans.readings(hand_trace(), 0, 160, ticks=2), **{lag: None})
+    assert reader("return_ms.rt")(types.SimpleNamespace(spans=r)) is None

@@ -1,8 +1,7 @@
 """The one traffic generator: audio pools and stream layouts from a mix file.
 
-Every stream uploads the 16-channel FV_Norm frame of each hop, as edge
-devices with the analog FEx do; the harness makes the frames from the
-pool's audio with the benchmark's own frontend.
+The pool is audio: one float32 row of ``hop_samples`` samples per hop.
+What a stream sends of it is the mix's ``input``.
 
 A mix (``bench/traffic/<name>.json``) is data only:
 
@@ -11,6 +10,12 @@ A mix (``bench/traffic/<name>.json``) is data only:
                   the server kept up; "drain": a backlog of stored audio,
                   fed as fast as the server retires ticks.
   streams         fleet size (open streams, one slot each).
+  input           "fv" (the default when the key is absent): every
+                  stream uploads the 16-channel FV_Norm frame of each
+                  hop, as edge devices with the analog FEx do, made by
+                  the reference's frontend from the pool's audio; "audio":
+                  every stream sends its raw hop and the server runs the
+                  frontend.
   audio           "speech": every stream carries speech-like audio;
                   "quiet": low-level noise with 1 s utterances whose
                   onsets arrive in bursts.
