@@ -215,8 +215,9 @@ class KWSPipeline:
         return self.features(audio)
 
     def _postprocess(self, fv_raw, state: FrontendState) -> jnp.ndarray:
-        """FV_Raw codes -> FV_Norm: the chip's digital back-end (log LUT,
-        normalizer, Q6.8 saturation), shared by every frontend."""
+        """FV_Raw codes -> FV_Norm: the chip's digital back-end (log ROM,
+        read under the ``kws_log_rom`` scope; normalizer; Q6.8
+        saturation), shared by every frontend."""
         x = fv_raw
         if self.config.use_log:
             x = quant.log_compress_lut(

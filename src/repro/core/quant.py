@@ -127,35 +127,93 @@ def quantize_unsigned(x: jnp.ndarray, bits: int, x_max: float) -> jnp.ndarray:
 
     Mirrors the DeltaSigma-TDC + decimation output register width. Values
     are clipped (the TDC count register saturates).
+
+    The division by ``x_max`` stays a division: XLA rewrites a division
+    by a constant into a multiplication by its reciprocal, which lands
+    on the other side of a rounding tie for some frames (x / 0.7 * 4095
+    = 260.5 exactly in float32 at x = 0.04452992 came out 261), so the
+    full scale reaches it through an optimization barrier.
     """
     levels = 2**bits - 1
-    q = ste_round(jnp.clip(x, 0.0, x_max) / x_max * levels)
+    full = jax.lax.optimization_barrier(
+        jnp.asarray(x_max, jnp.result_type(x, 0.0)))
+    q = ste_round(jnp.clip(x, 0.0, x_max) / full * levels)
     return q  # float codes in [0, levels]; STE-differentiable
 
 
-def make_log_lut(in_bits: int = 12, out_bits: int = 10) -> jnp.ndarray:
-    """The 12-bit -> 10-bit logarithmic compression LUT (Section II).
+@functools.lru_cache(maxsize=None)
+def make_log_lut(in_bits: int = 12, out_bits: int = 10) -> np.ndarray:
+    """The 12-bit -> 10-bit logarithmic compression ROM (Section II).
 
     out = round((2^out_bits - 1) * log2(1 + v) / log2(2^in_bits)) — a
     monotone logarithmic companding curve covering the full input range,
-    exactly representable as a 4096-entry ROM on the IC.
+    a 4096-entry ROM on the IC. Computed in float64 on the host (numpy),
+    so it is one table on every platform: float32 log2 on a device
+    rounds some entries the other way (code 63, where 1023 * 6 / 12 =
+    511.5 is a tie, among them). Returned read-only, as float32.
     """
-    v = jnp.arange(2**in_bits, dtype=jnp.float32)
-    out = jnp.round(
-        (2.0**out_bits - 1.0) * jnp.log2(1.0 + v) / (in_bits * 1.0)
-    )
-    return out.astype(jnp.float32)
+    v = np.arange(2**in_bits, dtype=np.float64)
+    out = np.round((2.0**out_bits - 1.0) * np.log2(1.0 + v) / in_bits)
+    out = out.astype(np.float32)
+    out.setflags(write=False)
+    return out
 
 
-def log_compress_lut(codes: jnp.ndarray, in_bits: int = 12, out_bits: int = 10):
-    """Differentiable (STE) logarithmic compression of integer codes.
+@functools.lru_cache(maxsize=None)
+def _log_steps(in_bits: int, out_bits: int):
+    """``(base, thresholds, jumps)`` of `make_log_lut`: the table equals
+    ``base + sum(jumps[k] for k if code >= thresholds[k])`` at every code.
+    The 12 -> 10-bit table has 553 steps, of 1 to 85 codes each."""
+    table = make_log_lut(in_bits, out_bits).astype(np.int64)
+    jumps = np.diff(table)
+    if (jumps < 0).any():
+        raise ValueError("the log ROM is not monotone")
+    at = np.flatnonzero(jumps)
+    return int(table[0]), (at + 1).astype(np.int32), jumps[at].astype(np.int32)
 
-    On hardware this is a ROM lookup; here we evaluate the closed form and
-    round with STE so QAT can backprop through the FEx chain.
-    """
+
+def _log_closed_form(codes, in_bits: int, out_bits: int):
+    """The curve the ROM tabulates, in float: its STE gradient is the log
+    compression's (its value misses the table at ties, see above)."""
     x = jnp.clip(codes, 0.0, 2.0**in_bits - 1.0)
     out = (2.0**out_bits - 1.0) * jnp.log2(1.0 + x) / (in_bits * 1.0)
     return ste_round(out)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1, 2))
+def _log_rom(codes, in_bits: int, out_bits: int):
+    dtype = jnp.result_type(codes, 0.0)
+    x = jnp.round(jnp.clip(codes, 0, 2**in_bits - 1)).astype(jnp.int32)
+    return jax.lax.platform_dependent(
+        x,
+        cpu=lambda x: jnp.asarray(make_log_lut(in_bits, out_bits))[x],
+        default=lambda x: _count_steps(
+            x, *_log_steps(in_bits, out_bits)).astype(jnp.float32),
+    ).astype(dtype)
+
+
+@_log_rom.defjvp
+def _log_rom_jvp(in_bits, out_bits, primals, tangents):
+    _, dy = jax.jvp(lambda v: _log_closed_form(v, in_bits, out_bits),
+                    primals, tangents)
+    return _log_rom(*primals, in_bits, out_bits), dy
+
+
+def log_compress_lut(codes: jnp.ndarray, in_bits: int = 12, out_bits: int = 10):
+    """Logarithmic compression of FV_Raw codes: `make_log_lut` read at
+    ``round(clip(codes, 0, 2^in_bits - 1))``, with the gradient of the
+    closed form (STE) so QAT can backprop through the FEx chain.
+
+    An accelerator never indexes the table: it reads it as a count of
+    its steps, weighted by their size (`_count_steps`, the form the
+    sigmoid/tanh ROMs take; a v5e runs 1-D gathers slowly). The CPU
+    indexes it: XLA:CPU does not fuse the count's compares into its sum,
+    so a call over 2M codes would hold 4.5 GB of them. Both return the
+    table's code. Runs under the ``kws_log_rom`` scope (inside the
+    serving tick, ``kws_frontend/kws_log_rom``).
+    """
+    with jax.named_scope("kws_log_rom"):
+        return _log_rom(codes, in_bits, out_bits)
 
 
 # --------------------------------------------------------------------------
@@ -280,14 +338,16 @@ def _tanh_steps() -> Tuple[int, np.ndarray]:
     return _rom_steps(tanh_lut_q68())
 
 
-def _count_steps(codes: jnp.ndarray, base: int,
-                 thresholds: np.ndarray) -> jnp.ndarray:
-    """``base + #{k : codes >= thresholds[k]}`` elementwise, int32.
+def _count_steps(codes: jnp.ndarray, base: int, thresholds: np.ndarray,
+                 weights: Optional[np.ndarray] = None) -> jnp.ndarray:
+    """``base + sum(weights[k] for k if codes >= thresholds[k])``
+    elementwise, int32; each weight is 1 when ``weights`` is None.
 
-    Equal to the ROM indexed at ``clip(codes, _LUT_MIN, _LUT_MAX)`` for
-    every int32 code (below every threshold is the first entry, above
-    all of them the last), with no gather: one fused compare-and-sum
-    per element, plain vector work on every backend. The thresholds run
+    Equal to the ROM indexed at the code clipped to its domain for every
+    int32 code (below every threshold is the first entry, above all of
+    them the last), with no gather: on a TPU one fused compare-and-sum
+    per element, plain vector work (XLA:CPU keeps every compare in
+    memory before the sum). The thresholds run
     along the leading axis, so the sum accumulates element-wise, and the
     codes are laid out 128 to a row where their count allows: a TPU pads
     the 48 lanes of a (streams, 48) gate to 128 (on a v5e this form took
@@ -297,7 +357,10 @@ def _count_steps(codes: jnp.ndarray, base: int,
     shape = codes.shape
     if codes.size % 128 == 0:
         codes = codes.reshape(-1, 128)
-    hits = codes >= thresholds.reshape((-1,) + (1,) * codes.ndim)
+    steps = (-1,) + (1,) * codes.ndim
+    hits = codes >= thresholds.reshape(steps)
+    if weights is not None:
+        hits = jnp.where(hits, weights.reshape(steps), 0)
     return (base + jnp.sum(hits, axis=0, dtype=jnp.int32)).reshape(shape)
 
 

@@ -77,9 +77,11 @@ def tick_reference(
 
     Each stage runs under a `jax.named_scope`, so its device ops carry
     the scope in their HLO metadata (``op_name``): ``kws_frontend``
-    (raw audio only), ``kws_cascade``, ``kws_classifier`` — inside
-    which the integer classifier names ``kws_gru{l}_gemm`` /
-    ``kws_gru{l}_gates`` / ``kws_gru{l}_update`` and ``kws_head``
+    (raw audio only; its log ROM read is ``kws_frontend/kws_log_rom``,
+    `repro.core.quant.log_compress_lut`), ``kws_cascade``,
+    ``kws_classifier`` — inside which the integer classifier names
+    ``kws_gru{l}_gemm`` / ``kws_gru{l}_gates`` / ``kws_gru{l}_update``
+    and ``kws_head``
     (`repro.core.gru_int.int_gru_classifier_step`) — and
     ``kws_smooth`` (softmax, smoothing, masked selects, argmax).
     Scopes change metadata only, never a value.

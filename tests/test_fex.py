@@ -76,6 +76,18 @@ def test_quantizer_monotone_and_range():
     assert float(q.min()) == 0.0 and float(q.max()) == 4095.0
 
 
+@pytest.mark.parametrize("frame,code", [(0.04452992, 260.0), (0.011025642, 64.0)])
+def test_quantizer_divides_by_the_full_scale(frame, code):
+    """Frames whose x / 0.7 * 4095 is a float32 tie (260.5, 64.5) round to
+    the even code; a multiplication by the reciprocal of 0.7, which XLA
+    makes of a division by a constant, rounded them up under jit."""
+    x = np.float32(frame)
+    assert np.float32(x / np.float32(0.7)) * np.float32(4095) == code + 0.5
+    for fn in (quant.quantize_unsigned,
+               jax.jit(quant.quantize_unsigned, static_argnums=(1, 2))):
+        assert float(fn(jnp.full((4,), x), 12, 0.7)[0]) == code
+
+
 def test_log_compress_monotone_10bit():
     codes = jnp.arange(4096.0)
     out = quant.log_compress_lut(codes, 12, 10)
@@ -83,6 +95,52 @@ def test_log_compress_monotone_10bit():
     assert float(out.min()) == 0.0 and float(out.max()) == 1023.0
     lut = quant.make_log_lut(12, 10)
     np.testing.assert_allclose(out, lut, atol=0)
+
+
+# The 12 -> 10-bit ROM in float64. Codes 3, 63 and 1023 sit on ties
+# (1023 * log2(1 + v) / 12 = 170.5, 511.5 and 852.5), which a float32
+# log2 a hair off rounds the other way: under jit code 63 came out 511.
+_CODES = np.arange(4096.0)
+_TABLE = np.round(1023.0 * np.log2(1.0 + _CODES) / 12.0)
+
+
+def _ste_closed_form(v):
+    """The log compression as it was evaluated before the ROM read: its
+    gradient is the one QAT keeps."""
+    x = jnp.clip(v, 0.0, 4095.0)
+    return quant.ste_round(1023.0 * jnp.log2(1.0 + x) / 12.0)
+
+
+@pytest.mark.parametrize(
+    "case", ["eager", "jit", "step_count", "make_log_lut", "gradient"])
+def test_log_rom_is_the_float64_table(case):
+    assert _TABLE[[3, 63, 1023]].tolist() == [170.0, 512.0, 852.0]
+    codes = jnp.asarray(_CODES, jnp.float32)
+    if case == "eager":
+        got = quant.log_compress_lut(codes, 12, 10)
+    elif case == "jit":
+        got = jax.jit(quant.log_compress_lut, static_argnums=(1, 2))(
+            codes, 12, 10)
+    elif case == "step_count":  # the form accelerators run, on the CPU
+        got = jax.jit(lambda c: quant._count_steps(
+            c, *quant._log_steps(12, 10)))(
+            jnp.arange(-70, 4096 + 70, dtype=jnp.int32))
+        want = _TABLE[np.clip(np.arange(-70, 4096 + 70), 0, 4095)]
+        np.testing.assert_array_equal(np.asarray(got), want)
+        return
+    elif case == "make_log_lut":
+        got = quant.make_log_lut(12, 10)
+    else:
+        spread = jnp.asarray([-3.0, 0.0, 1.0, 3.0, 17.0, 63.0, 64.0, 512.0,
+                              1023.0, 2047.5, 4095.0, 4100.0])
+        got = jax.vmap(jax.grad(
+            lambda v: quant.log_compress_lut(v, 12, 10)))(spread)
+        want = jax.vmap(jax.grad(_ste_closed_form))(spread)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert float(got[2]) > 0.0
+        return
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(got), _TABLE)
 
 
 def test_ablation_paths_differ_and_norm_is_zero_mean():

@@ -174,6 +174,25 @@ def test_tick_ops_carry_stable_names_on_v5e(one_chip, norm_stats, params):
     assert set(gates) == {"kws_gru0_gates", "kws_gru1_gates"}
 
 
+def test_audio_tick_reads_the_log_rom_without_gather_on_v5e(one_chip,
+                                                            norm_stats,
+                                                            params):
+    """The raw-audio tick's 12 -> 10-bit log ROM is read as a count of its
+    steps: a fusion under ``kws_frontend/kws_log_rom``, and no gather
+    anywhere in the program."""
+    srv = StreamingKWSServer(_pipe(norm_stats, "integer"), params,
+                             max_streams=N_STREAMS)
+    with force_dispatch("pallas"):
+        text = srv._tick_audio.lower(
+            *_operands(srv, True, one_chip)
+        ).compile().as_text()
+    assert not re.search(r"\sgather\(", text)
+    entry = text[text.index("\nENTRY"):].splitlines()
+    rom = [ln for ln in entry if " fusion(" in ln and re.search(
+        r'op_name="[^"]*/kws_frontend/kws_log_rom/', ln)]
+    assert rom
+
+
 def test_cascaded_auto_tick_compiles_for_v5e(one_chip, norm_stats, params):
     srv = StreamingKWSServer(
         _pipe(norm_stats, "integer", CascadeConfig()), params,

@@ -190,7 +190,7 @@ _SCOPES = ("kws_classifier", "kws_gru0_gemm", "kws_gru0_gates",
            "kws_gru1_update", "kws_head", "kws_smooth")
 
 
-@pytest.mark.parametrize("kind", ["integer", "cascade", "mesh"])
+@pytest.mark.parametrize("kind", ["integer", "cascade", "mesh", "audio"])
 def test_compiled_tick_carries_every_scope(norm_stats, params, kind):
     cascade = CascadeConfig() if kind == "cascade" else None
     devices = 4 if kind == "mesh" else None
@@ -201,6 +201,20 @@ def test_compiled_tick_carries_every_scope(norm_stats, params, kind):
     dim = srv.pipeline.config.fex.num_channels
     slab = np.zeros((MAX_STREAMS, dim), np.float32)
     mask = np.ones((MAX_STREAMS,), bool)
+    if kind == "audio":
+        # the raw-audio tick compiled: its frontend's ops, and the log ROM
+        # read within it, carry their scopes
+        text = srv._tick_audio.lower(
+            srv.params, srv.state,
+            np.zeros((MAX_STREAMS, srv.pipeline.chunk_samples), np.float32),
+            mask, srv.frontend_state, srv.smoothing,
+        ).compile().as_text()
+        scopes = {"/".join(p for p in op.split("/") if p.startswith("kws_"))
+                  for op in re.findall(r'op_name="([^"]*)"', text)}
+        assert {"kws_frontend", "kws_frontend/kws_log_rom"} <= scopes
+        assert set(re.findall(r"kws_\w+", text)) == (
+            set(_SCOPES) | {"kws_frontend", "kws_log_rom"})
+        return
     text = srv._tick_fv.lower(
         srv.params, srv.state, slab, mask, srv.frontend_state,
         srv.smoothing,
