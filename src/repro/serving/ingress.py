@@ -11,13 +11,14 @@ returns to the host between ticks).
 
 This module closes that gap without touching the tick itself:
 
-  * `TickHandle` — the deferred result of one dispatched tick. The
-    server hands it back immediately after (non-blocking) dispatch; the
-    scores materialize on first `result()`. The handle owns device-side
-    copies of the tick's outputs, so it stays valid however many later
-    ticks donate the `ServerState` buffers the raw outputs alias — a
-    handle fetched two ticks late reads exactly what a synchronous
-    fetch would have.
+  * `TickHandle` — the deferred result of one dispatched tick: the one
+    lane-dense buffer the tick program packs its (scores, top) into
+    (`pack_result`). The server starts that buffer's copy to the host
+    at dispatch and hands the handle back at once; the scores
+    materialize on first `result()`. The packed buffer is a fresh
+    output of the tick, never a `ServerState` leaf, so later ticks
+    that donate the state leave it alone — a handle fetched two ticks
+    late reads exactly what a synchronous fetch would have.
   * `PipelinedIngress` — preallocated ping-pong host staging. `stage()`
     hands out a (slab, mask) buffer pair to assemble the next tick into
     while the previous tick is still in flight; `commit()` dispatches
@@ -48,11 +49,14 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.metrics import span
 
 __all__ = [
+    "pack_result",
+    "unpack_result",
     "TickHandle",
     "PipelinedIngress",
     "TickCoalescer",
@@ -60,15 +64,42 @@ __all__ = [
 ]
 
 
+def pack_result(scores, top):
+    """A tick's (scores (..., N, K) float32, top (..., N) int32) as one
+    lane-dense int32 buffer (..., K + 1, N), under the ``kws_pack``
+    scope: rows 0..K-1 hold the scores transposed, their float32 bits
+    unchanged (bitcast), row K holds top. With the stream axis minor, a
+    TPU's (8, 128) tiles carry 13 rows of N streams instead of 12 of
+    128 lanes per stream, and the host fetches one buffer, not two.
+    Integers all the way: no float path can flush top's small values."""
+    with jax.named_scope("kws_pack"):
+        bits = jax.lax.bitcast_convert_type(
+            jnp.swapaxes(scores, -1, -2), jnp.int32
+        )
+        return jnp.concatenate(
+            [bits, jnp.expand_dims(top.astype(jnp.int32), -2)], axis=-2
+        )
+
+
+def unpack_result(packed: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(scores (..., N, K) float32, top (..., N) int32) as views of a
+    host `pack_result` buffer: the very bits the tick computed."""
+    k = packed.shape[-2] - 1
+    scores = np.swapaxes(packed[..., :k, :].view(np.float32), -1, -2)
+    return scores, packed[..., k, :]
+
+
 class TickHandle:
     """Deferred result of one asynchronously dispatched serving tick.
 
-    Holds device-side OWNED copies of the tick's (scores, top) outputs
-    — never the raw tick outputs, which can alias `ServerState` buffers
-    that the NEXT tick donates. `result()` blocks until the tick (and
-    the copy chained behind it) has executed, materializes owned host
-    arrays, and caches them; the device arrays are dropped at that
-    point so steady-state serving holds at most `depth` tick outputs.
+    Holds the tick's packed result (`pack_result`) — a fresh output of
+    the tick program, never one of the `ServerState` buffers the NEXT
+    tick donates, so it needs no copy of its own to outlive them. The
+    server has started its copy to the host at dispatch. `result()`
+    blocks until the tick has executed, materializes the buffer once as
+    an owned host array, returns (scores, top) as views of it, and
+    caches them; the device array is dropped at that point so
+    steady-state serving holds at most `depth` tick outputs.
 
     `meta` is caller-owned freight (e.g. a submit timestamp or the
     {stream_id: slot} map of a coalesced tick); `done_at` records the
@@ -82,25 +113,28 @@ class TickHandle:
     The first `result()` runs under the ``kws.handle.fetch`` span
     (children ``kws.handle.wait``, ``kws.handle.d2h``) tagged with
     `tick`, the server's dispatch number, and observed into
-    `fetch_hist` when given (the server's ``kws_serve_tick_fetch_ms``).
-    `dispatched_at` is the end of the dispatch span.
+    `fetch_hist` when given (the server's ``kws_serve_tick_fetch_ms``);
+    its one device-to-host transfer increments `transfers` when given
+    (``kws_serve_d2h_transfers_total``). `dispatched_at` is the end of
+    the dispatch span.
     """
 
-    __slots__ = ("_scores", "_top", "_host", "meta", "tick",
-                 "dispatched_at", "done_at", "_fetch_hist", "_clock")
+    __slots__ = ("_packed", "_host", "meta", "tick", "dispatched_at",
+                 "done_at", "_fetch_hist", "_transfers", "_clock")
 
-    def __init__(self, scores, top, meta: Any = None,
+    def __init__(self, packed, meta: Any = None,
                  tick: Optional[int] = None,
                  dispatched_at: Optional[float] = None, fetch_hist=None,
+                 transfers=None,
                  clock: Callable[[], float] = time.perf_counter):
-        self._scores = scores
-        self._top = top
+        self._packed = packed
         self._host: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self.meta = meta
         self.tick = tick
         self.dispatched_at = dispatched_at
         self.done_at: Optional[float] = None
         self._fetch_hist = fetch_hist
+        self._transfers = transfers
         self._clock = clock
 
     def ready(self) -> bool:
@@ -109,7 +143,7 @@ class TickHandle:
         if self._host is not None:
             return True
         try:
-            ok = bool(self._scores.is_ready() and self._top.is_ready())
+            ok = bool(self._packed.is_ready())
         except AttributeError:  # non-jax array stand-ins
             ok = True
         if ok and self.done_at is None:
@@ -117,18 +151,21 @@ class TickHandle:
         return ok
 
     def result(self) -> Tuple[np.ndarray, np.ndarray]:
-        """(scores (N, K), top (N,)) as owned host arrays; blocks until
-        the tick has executed. Idempotent — later calls return the
-        cached copy, so fetching a handle after further ticks (or slot
-        resets) ran is always safe."""
+        """(scores (N, K), top (N,)) — (W, N, K), (W, N) for a window —
+        as views of one owned host array; blocks until the tick has
+        executed. Idempotent — later calls return the cached views, so
+        fetching a handle after further ticks (or slot resets) ran is
+        always safe."""
         if self._host is None:
             with span("kws.handle.fetch", self._fetch_hist, self._clock,
                       tick=self.tick) as sp:
                 with span("kws.handle.wait", tick=self.tick):
-                    jax.block_until_ready((self._scores, self._top))
+                    jax.block_until_ready(self._packed)
                 with span("kws.handle.d2h", tick=self.tick):
-                    self._host = (np.array(self._scores), np.array(self._top))
-            self._scores = self._top = None
+                    self._host = unpack_result(np.array(self._packed))
+                    if self._transfers is not None:
+                        self._transfers.inc()
+            self._packed = None
             if self.done_at is None:
                 self.done_at = sp.end
         return self._host

@@ -38,9 +38,10 @@ The spans, a contract once a benchmark reads them (PERF.md names the
 metric each feeds): ``kws.ingress.stage`` (child
 ``kws.ingress.reuse_wait``, only when a staging buffer's previous tick
 had to be forced), ``kws.ingress.commit`` > ``kws.server.dispatch`` >
-``kws.server.tick_call``, ``kws.server.own_copy`` (both under
-``kws.server.compile`` on a dispatch that traces a new program or
-shape), ``kws.server.step`` (synchronous `step_batch`), and
+``kws.server.tick_call`` (under ``kws.server.compile`` on a dispatch
+that traces a new program or shape), ``kws.server.prefetch`` (one per
+dispatch: the packed result's copy to the host, started),
+``kws.server.step`` (synchronous `step_batch`), and
 ``kws.handle.fetch`` > ``kws.handle.wait``, ``kws.handle.d2h``.
 
 Everything is host-side Python: no device code, no forced syncs, no

@@ -97,7 +97,7 @@ from repro.distributed.sharding import (
 )
 from repro.models.registry import get_backbone
 from repro.serving.autoscale import StreamRouter
-from repro.serving.ingress import TickHandle
+from repro.serving.ingress import TickHandle, pack_result
 from repro.serving.metrics import MetricsRegistry, span
 
 Pytree = Any
@@ -317,10 +317,18 @@ def _fused_tick(pipeline, raw_audio, params, state: ServerState, inp,
     )
 
 
-def _own_copies(scores, top):
-    """See `_own`; named, so that its device program reads
-    ``jit__own_copies`` in a profile."""
-    return jnp.copy(scores), jnp.copy(top)
+def _tick(pipeline, raw_audio, params, state: ServerState, inp, mask,
+          frontend_state, smoothing, *, tick_impl="xla", mesh=None):
+    """The live tick's device program: `_fused_tick`, with its (scores,
+    top) packed into the one lane-dense buffer the host fetches
+    (`repro.serving.ingress.pack_result`). The buffer is a fresh value
+    that matches no donated state leaf, so nothing aliases it and a
+    handle holding it outlives every later tick's donation."""
+    state, scores, top = _fused_tick(
+        pipeline, raw_audio, params, state, inp, mask, frontend_state,
+        smoothing, tick_impl=tick_impl, mesh=mesh,
+    )
+    return state, pack_result(scores, top)
 
 
 def _reset_slot(state: ServerState, slot) -> ServerState:
@@ -534,7 +542,7 @@ class StreamingKWSServer:
             metrics = None
         self.metrics: Optional[MetricsRegistry] = metrics
         self._clock = time.perf_counter if metrics is None else metrics.clock
-        self._m_dispatch = self._m_fetch = self._m_tick = None
+        self._m_dispatch = self._m_fetch = self._m_tick = self._m_d2h = None
         if metrics is not None:
             self._m_ticks = metrics.counter(
                 "kws_serve_ticks_total",
@@ -560,6 +568,11 @@ class StreamingKWSServer:
                 "kws_serve_tick_fetch_ms",
                 "host time blocked in TickHandle.result() fetching "
                 "scores to host",
+            )
+            self._m_d2h = metrics.counter(
+                "kws_serve_d2h_transfers_total",
+                "device-to-host transfers issued by tick fetches: one "
+                "packed result per dispatch",
             )
             self._m_tick = metrics.histogram(
                 "kws_serve_tick_ms",
@@ -619,6 +632,9 @@ class StreamingKWSServer:
             vec = NamedSharding(mesh, P(STREAM_AXIS))
             seq_row = NamedSharding(mesh, P(None, STREAM_AXIS, None))
             seq_vec = NamedSharding(mesh, P(None, STREAM_AXIS))
+            # the packed result, (K + 1, N) per tick: stream axis minor
+            packed = NamedSharding(mesh, P(None, STREAM_AXIS))
+            seq_packed = NamedSharding(mesh, P(None, None, STREAM_AXIS))
             scalar = NamedSharding(mesh, P())
             tick_kw = dict(
                 donate_argnums=(1,),
@@ -626,7 +642,7 @@ class StreamingKWSServer:
                     rep(self.params), st_sh, row, vec,
                     rep(self.frontend_state), scalar,
                 ),
-                out_shardings=(st_sh, row, vec),
+                out_shardings=(st_sh, packed),
             )
             run_kw = dict(
                 donate_argnums=(1,),
@@ -634,7 +650,7 @@ class StreamingKWSServer:
                     rep(self.params), st_sh, seq_row, seq_vec,
                     rep(self.frontend_state), scalar,
                 ),
-                out_shardings=(st_sh, seq_row, seq_vec),
+                out_shardings=(st_sh, seq_packed),
             )
             reset_kw = dict(
                 donate_argnums=(0,),
@@ -643,11 +659,11 @@ class StreamingKWSServer:
             )
         impl_kw = dict(tick_impl=self.tick_impl, mesh=mesh)
         self._tick_audio = jax.jit(
-            functools.partial(_fused_tick, pipeline, True, **impl_kw),
+            functools.partial(_tick, pipeline, True, **impl_kw),
             **tick_kw,
         )
         self._tick_fv = jax.jit(
-            functools.partial(_fused_tick, pipeline, False, **impl_kw),
+            functools.partial(_tick, pipeline, False, **impl_kw),
             **tick_kw,
         )
         self._reset = jax.jit(_reset_slot, **reset_kw)
@@ -659,15 +675,6 @@ class StreamingKWSServer:
             functools.partial(_run_scan, pipeline, False, **impl_kw),
             **run_kw,
         )
-        # Device-side ownership copy for the async path: the fused
-        # tick's (scores, top) outputs can alias the new ServerState's
-        # buffers, which the NEXT tick donates — a deferred host fetch
-        # of the raw outputs would read garbage. jnp.copy under jit
-        # (no donation) always produces fresh buffers, dispatched
-        # asynchronously right behind the tick, so a TickHandle stays
-        # valid however late it is fetched. Shardings are inherited
-        # from the inputs, so the same program serves the mesh path.
-        self._own = jax.jit(_own_copies)
 
     # ---- observability ----
 
@@ -920,8 +927,8 @@ class StreamingKWSServer:
         new placement is balanced and oracle-predictable). Surviving
         streams are BIT-identical to an un-resized server afterwards —
         all five classifier backends, cascaded detector state, ΔGRU
-        counters, async handles in flight (handles own their copies)
-        — proven in tests/test_serve_sharded.py.
+        counters, async handles in flight (a handle's packed result
+        is no state leaf) — proven in tests/test_serve_sharded.py.
 
         The mesh is unchanged, so no device program is rebuilt; the
         existing jits retrace at the new slot-axis shape and previously
@@ -1151,8 +1158,9 @@ class StreamingKWSServer:
 
         Returns (scores (max_streams, K), top (max_streams,)) as host
         arrays; rows of unsubmitted slots hold their previous values.
-        The arrays are OWNED copies (never views of donation-bound
-        buffers): this is `step_batch_async` fetched immediately.
+        Both are views of one owned host array (never of a
+        donation-bound buffer): this is `step_batch_async` fetched
+        immediately.
         """
         with span("kws.server.step", self._m_tick, self._clock,
                   tick=self.dispatch_seq):
@@ -1171,10 +1179,10 @@ class StreamingKWSServer:
         arrival merging), which is what closes the live-vs-scan
         throughput gap.
 
-        The handle owns device-side copies of the tick's outputs
-        (dispatched right behind the tick, still non-blocking), so it
-        survives any number of later ticks donating the `ServerState`
-        buffers the raw outputs alias — fetch it as late as you like.
+        The handle holds the tick's packed result (`_tick`), a fresh
+        buffer no `ServerState` leaf aliases, whose copy to the host
+        starts at dispatch; it survives any number of later ticks
+        donating the state — fetch it as late as you like.
         The state trajectory is bit-identical to the synchronous
         `step_batch` sequence: async moves only WHEN the host reads the
         results, never what the device computes.
@@ -1218,9 +1226,9 @@ class StreamingKWSServer:
         a host round-trip every 16 ms. Compiles once per (n_ticks, kind).
 
         Returns (scores_seq (n_ticks, N, K), tops (n_ticks, N)) as host
-        arrays and advances the server state by n_ticks. The arrays are
-        owned copies, never views of donation-bound buffers: this is
-        `run_batch_async` fetched immediately.
+        arrays and advances the server state by n_ticks. Both are views
+        of one owned host array, never of donation-bound buffers: this
+        is `run_batch_async` fetched immediately.
         """
         return self.run_batch_async(slab, mask).result()
 
@@ -1236,8 +1244,8 @@ class StreamingKWSServer:
         `window` sequential `step_batch` calls — which is what lets the
         async ingress amortize the per-dispatch host cost over a whole
         window (`PipelinedIngress(window=K)`) without touching the
-        correctness story. Same owned-copy fetch discipline as
-        `step_batch_async`.
+        correctness story. Same packed, prefetched result as
+        `step_batch_async`, with a leading window axis.
         """
         raw = self._is_raw(int(np.shape(slab)[-1]))
         fn = self._run_audio if raw else self._run_fv
@@ -1247,8 +1255,9 @@ class StreamingKWSServer:
     def _dispatch(self, fn, program: str, slab, mask,
                   n_ticks: int) -> TickHandle:
         """Enqueue one device call of `fn` (a tick or a scanned window)
-        and the owned copies of its outputs, under the dispatch spans;
-        returns the handle, which carries the dispatch's number."""
+        and start its packed result's copy to the host, under the
+        dispatch spans; returns the handle, which carries the
+        dispatch's number."""
         k = self.dispatch_seq
         self.dispatch_seq += 1
         compiles = self._note_dispatch(program, np.shape(slab))
@@ -1257,16 +1266,19 @@ class StreamingKWSServer:
             with (span("kws.server.compile", tick=k) if compiles
                   else contextlib.nullcontext()):
                 with span("kws.server.tick_call", tick=k):
-                    self.state, scores, top = fn(
+                    self.state, packed = fn(
                         self.params, self.state, slab, mask,
                         self.frontend_state, self.smoothing,
                     )
-                with span("kws.server.own_copy", tick=k):
-                    scores, top = self._own(scores, top)
+            # the transfer begins as soon as the device finishes, not
+            # when a fetch first asks for the result
+            with span("kws.server.prefetch", tick=k):
+                packed.copy_to_host_async()
         if self.metrics is not None:
             self._m_ticks.inc(n_ticks)
-        return TickHandle(scores, top, tick=k, dispatched_at=sp.end,
-                          fetch_hist=self._m_fetch, clock=self._clock)
+        return TickHandle(packed, tick=k, dispatched_at=sp.end,
+                          fetch_hist=self._m_fetch, transfers=self._m_d2h,
+                          clock=self._clock)
 
     def run(self, buffers: Dict[int, np.ndarray]) -> Dict[int, dict]:
         """Offline replay: buffered audio -> per-tick posteriors, scanned.
@@ -1315,19 +1327,18 @@ class StreamingKWSServer:
 
 def _run_scan(pipeline, raw_audio, params, state: ServerState, slab, mask,
               frontend_state, smoothing, *, tick_impl="xla", mesh=None):
-    """lax.scan of the fused tick over (n_ticks, N, S|C) buffered input.
+    """lax.scan of the live tick over (n_ticks, N, S|C) buffered input.
 
-    The scan body is the very `_fused_tick` the live path jits — same
+    The scan body is the very `_tick` the live path jits — same
     tick_impl, so a fused-pallas server replays its megakernel inside
-    the scan too (one kernel launch per scanned tick)."""
+    the scan too (one kernel launch per scanned tick) — and returns
+    its packed results stacked, (n_ticks, K + 1, N)."""
 
     def body(st, xs):
         x_t, m_t = xs
-        st, scores, top = _fused_tick(
+        return _tick(
             pipeline, raw_audio, params, st, x_t, m_t, frontend_state,
             smoothing, tick_impl=tick_impl, mesh=mesh,
         )
-        return st, (scores, top)
 
-    state, (scores_seq, tops) = jax.lax.scan(body, state, (slab, mask))
-    return state, scores_seq, tops
+    return jax.lax.scan(body, state, (slab, mask))

@@ -456,11 +456,10 @@ def test_step_twice_keeps_first_scores_single_device():
     """Donation-hazard regression (single-device path; the sharded twin
     lives in tests/test_serve_sharded.py): two ticks back-to-back
     without fetching `scores` in between must leave the first tick's
-    returned arrays intact. The tick's scores output can alias the new
-    state's scores buffer, and that buffer is DONATED to the next tick
-    — a zero-copy `np.asarray` view of it would turn into
-    read-after-donation garbage, so the host boundary must hand out
-    owned copies."""
+    returned arrays intact. The state's buffers are DONATED to the
+    next tick, so the host boundary hands out views of one owned host
+    copy of the tick's packed result — never a zero-copy view of
+    device memory."""
     _, srv = _server(seed=17)
     srv.open_stream(0)
     srv.open_stream(1)
@@ -470,7 +469,7 @@ def test_step_twice_keeps_first_scores_single_device():
     fv1 = rng.standard_normal((srv.max_streams, 16)).astype(np.float32)
     fv2 = rng.standard_normal((srv.max_streams, 16)).astype(np.float32)
     scores1, top1 = srv.step_batch(fv1, mask)
-    assert scores1.flags["OWNDATA"] and top1.flags["OWNDATA"]
+    assert scores1.base is top1.base and scores1.base.flags["OWNDATA"]
     snap_s, snap_t = scores1.copy(), top1.copy()
     view = srv.scores  # the property must also be an owned copy
     assert view.flags["OWNDATA"]
@@ -482,7 +481,7 @@ def test_step_twice_keeps_first_scores_single_device():
     # same guard on the scanned replay driver
     slab = rng.standard_normal((2, srv.max_streams, 16)).astype(np.float32)
     seq, tops = srv.run_batch(slab, np.stack([mask, mask]))
-    assert seq.flags["OWNDATA"] and tops.flags["OWNDATA"]
+    assert seq.base is tops.base and seq.base.flags["OWNDATA"]
     snap_seq = seq.copy()
     srv.run_batch(slab, np.stack([mask, mask]))
     np.testing.assert_array_equal(seq, snap_seq)

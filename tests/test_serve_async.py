@@ -25,6 +25,9 @@ later in time. This suite proves it with `np.testing.assert_array_equal`
     submitted frames, never on when handles were fetched.
 """
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,12 +36,14 @@ import pytest
 from repro.core import quant
 from repro.core.fex import fit_norm_stats
 from repro.core.pipeline import KWSPipeline, KWSPipelineConfig
+from repro.kernels.tick_fused import tick_reference
 from repro.serving.cascade import CascadeConfig
 from repro.serving.ingress import (
     CoalescedTick,
     PipelinedIngress,
     TickCoalescer,
     TickHandle,
+    pack_result,
 )
 from repro.serving.serve_loop import StreamingKWSServer
 
@@ -95,6 +100,13 @@ def _state_leaves(srv):
         np.asarray(leaf).copy()
         for leaf in jax.tree_util.tree_leaves(srv.state)
     ]
+
+
+def _assert_views_of_one_owned_array(scores, top):
+    """A fetched (scores, top): views of one host array that owns its
+    data — never of a device buffer a later tick could donate."""
+    assert scores.base is not None and scores.base is top.base
+    assert scores.base.flags["OWNDATA"]
 
 
 def _assert_states_identical(a, b):
@@ -259,19 +271,121 @@ def test_handle_survives_later_ticks_and_slot_resets(qat_server):
     got3b = h3.result()  # idempotent: cached host copy
     assert got3a is got3b
     assert h3.ready() and h3.done_at is not None
-    assert got3a[0].flags["OWNDATA"] and got3a[1].flags["OWNDATA"]
+    _assert_views_of_one_owned_array(*got3a)
 
 
 def test_step_batch_is_async_fetched_immediately(qat_server):
     """The sync path IS the async path + immediate result(): same
-    arrays, owned copies."""
+    arrays, views of one owned host copy."""
     pipe, srv = qat_server
     _reset(srv)
     slab, mask = _ticks(pipe, 1, "fv", seed=12)[0]
     scores, top = srv.step_batch(slab, mask)
-    assert scores.flags["OWNDATA"] and top.flags["OWNDATA"]
+    _assert_views_of_one_owned_array(scores, top)
     assert scores.shape == (MAX_STREAMS, pipe.config.gru.num_classes)
     assert top.shape == (MAX_STREAMS,)
+
+
+# --------------------------------------------------------------------------
+# the packed result: tick_reference's own bits, never aliased
+# --------------------------------------------------------------------------
+
+def _reference_outputs(pipe, srv, ticks, raw):
+    """`tick_reference`'s own (scores, top), no packing, over `ticks`
+    from `srv`'s current state (which is read, never donated)."""
+    fn = jax.jit(functools.partial(tick_reference, pipe, raw))
+    st = srv.state
+    state4 = (st.gru, st.carry, st.scores, st.det)
+    out = []
+    for slab, mask in ticks:
+        state4, scores, top = fn(srv.params, state4, slab, mask,
+                                 srv.frontend_state, srv.smoothing)
+        out.append((np.asarray(scores), np.asarray(top)))
+    return out
+
+
+@pytest.mark.parametrize(
+    "kind", ["integer", "cascade", "audio", "sharded", "window"]
+)
+def test_packed_result_is_tick_reference_bit_for_bit(norm_stats, kind):
+    """What a handle unpacks on the host is `tick_reference`'s
+    (scores, top), bit for bit — for the integer, cascaded, raw-audio
+    and mesh-sharded servers and a `run_batch_async` window — with
+    every handle fetched only after the last dispatch and a slot reset
+    that rewrote state in place."""
+    if kind == "sharded" and N_DEV < 2:
+        pytest.skip("needs a multi-device platform")
+    pipe = KWSPipeline(
+        KWSPipelineConfig(
+            classifier="integer",
+            cascade=(CascadeConfig(wake_threshold=0.3, hangover_frames=1)
+                     if kind == "cascade" else None),
+        ),
+        norm_stats=norm_stats,
+    )
+    params = pipe.init_params(jax.random.PRNGKey(9))
+    n = 2 * MESH_DEV if kind == "sharded" else MAX_STREAMS
+    raw = kind == "audio"
+    ticks = _ticks(pipe, 4, "audio" if raw else "fv", seed=17, n_streams=n)
+    srv = StreamingKWSServer(pipe, params, max_streams=n,
+                             devices=MESH_DEV if kind == "sharded" else None)
+    twin = StreamingKWSServer(pipe, params, max_streams=n)
+    for sid in range(n):
+        srv.open_stream(sid)
+        twin.open_stream(sid)
+    want = _reference_outputs(pipe, twin, ticks, raw)
+    if kind == "window":
+        handles = [srv.run_batch_async(np.stack([s for s, _ in ticks]),
+                                       np.stack([m for _, m in ticks]))]
+    else:
+        handles = [srv.step_batch_async(s, m) for s, m in ticks]
+    srv.close_stream(0)
+    srv.open_stream(100)  # _reset rewrites slot 0's state buffers
+    got = [h.result() for h in handles]
+    if kind == "window":
+        (scores_seq, tops), = got
+        assert scores_seq.shape == (len(ticks), n, 12)
+        got = list(zip(scores_seq, tops))
+    for (gs, gt), (ws, wt) in zip(got, want, strict=True):
+        assert gs.dtype == ws.dtype and gt.dtype == wt.dtype
+        np.testing.assert_array_equal(gs, ws)
+        np.testing.assert_array_equal(gt, wt)
+
+
+def _aliased_outputs(hlo_text):
+    """Output indices the compiled program's ``input_output_alias``
+    names (each reuses a donated input's buffer)."""
+    head = hlo_text.splitlines()[0]
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry_computation", head)
+    return {int(i) for i in re.findall(r"\{(\d+)\}:", m.group(1))} if m else set()
+
+
+@pytest.mark.parametrize("classifier", ["integer", "delta-int"])
+@pytest.mark.parametrize("max_streams", [1, 13], ids=["one", "odd"])
+def test_packed_output_is_never_aliased(norm_stats, classifier,
+                                        max_streams):
+    """The packed (K + 1, N) result is a fresh buffer: the compiled
+    tick and scanned window alias donated state into state outputs
+    only, never into the packed output — at a single stream and at an
+    odd capacity equal to K + 1, where the packed shape is square."""
+    pipe = KWSPipeline(KWSPipelineConfig(classifier=classifier),
+                       norm_stats=norm_stats)
+    srv = StreamingKWSServer(pipe, pipe.init_params(jax.random.PRNGKey(0)),
+                             max_streams=max_streams)
+    k = pipe.config.gru.num_classes
+    n_state = len(jax.tree_util.tree_leaves(srv.state))
+    dim = pipe.config.fex.num_channels
+    for fn, lead in ((srv._tick_fv, ()), (srv._run_fv, (2,))):
+        lowered = fn.lower(
+            srv.params, srv.state,
+            np.zeros(lead + (max_streams, dim), np.float32),
+            np.ones(lead + (max_streams,), bool),
+            srv.frontend_state, srv.smoothing,
+        )
+        assert lowered.out_info[1].shape == lead + (k + 1, max_streams)
+        aliased = _aliased_outputs(lowered.compile().as_text())
+        assert aliased and max(aliased) < n_state  # state outputs only
+        assert n_state not in aliased
 
 
 # --------------------------------------------------------------------------
@@ -613,10 +727,14 @@ def test_async_random_schedule_matches_lifecycle_oracle(
 
 def test_tick_handle_plain_arrays():
     """Non-jax stand-ins (plain numpy) work: ready() is immediately
-    True and result() copies to owned host arrays."""
-    h = TickHandle(np.arange(6.0).reshape(2, 3), np.array([1, 2]),
-                   meta="m")
+    True and result() unpacks views of one owned host copy."""
+    scores = np.arange(6.0, dtype=np.float32).reshape(2, 3)
+    top = np.array([1, 2], np.int32)
+    h = TickHandle(np.asarray(pack_result(scores, top)), meta="m")
     assert h.ready()
     s, t = h.result()
-    assert s.flags["OWNDATA"] and t.flags["OWNDATA"]
+    np.testing.assert_array_equal(s, scores)
+    np.testing.assert_array_equal(t, top)
+    assert s.dtype == np.float32 and t.dtype == np.int32
+    _assert_views_of_one_owned_array(s, t)
     assert h.meta == "m" and h.done_at is not None

@@ -372,8 +372,9 @@ def test_router_round_robin_balance():
 
 def test_step_twice_keeps_first_scores_sharded(server_pair):
     """Two ticks back-to-back without fetching `scores` in between: the
-    first tick's returned arrays must own their memory and stay intact
-    (a zero-copy view would alias a buffer donated to tick 2)."""
+    first tick's returned arrays must be views of one host array that
+    owns its memory, and stay intact (a zero-copy view of a device
+    buffer could alias a buffer donated to tick 2)."""
     _, sharded = server_pair
     _reset_pair(server_pair)
     for sid in range(MAX_STREAMS):
@@ -383,7 +384,7 @@ def test_step_twice_keeps_first_scores_sharded(server_pair):
     fv1 = rng.standard_normal((MAX_STREAMS, 16)).astype(np.float32)
     fv2 = rng.standard_normal((MAX_STREAMS, 16)).astype(np.float32)
     s1, t1 = sharded.step_batch(fv1, mask)
-    assert s1.flags["OWNDATA"] and t1.flags["OWNDATA"]
+    assert s1.base is t1.base and s1.base.flags["OWNDATA"]
     snap_s, snap_t = s1.copy(), t1.copy()
     view = sharded.scores
     assert view.flags["OWNDATA"]

@@ -218,7 +218,7 @@ def test_sharded_auto_tick_compiles_for_v5e_2x2(topo, one_chip, norm_stats,
         replicated_shardings,
         stream_shardings,
     )
-    from repro.serving.serve_loop import _fused_tick
+    from repro.serving.serve_loop import _tick
 
     mesh = Mesh(np.asarray(topo.devices), ("stream",))
     pipe = _pipe(norm_stats, classifier)
@@ -247,15 +247,36 @@ def test_sharded_auto_tick_compiles_for_v5e_2x2(topo, one_chip, norm_stats,
         jax.ShapeDtypeStruct((), jnp.float32, sharding=scalar),
     )
     tick = jax.jit(
-        functools.partial(_fused_tick, pipe, True, tick_impl="xla",
-                          mesh=mesh),
+        functools.partial(_tick, pipe, True, tick_impl="xla", mesh=mesh),
         donate_argnums=(1,), in_shardings=in_sh,
-        out_shardings=(st_sh, row, vec),
+        out_shardings=(st_sh, NamedSharding(mesh, P(None, "stream"))),
     )
     with force_dispatch("pallas"):
         text = tick.lower(*operands).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 5
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+def test_packed_result_is_stream_minor_on_v5e(one_chip, norm_stats, params):
+    """The one output a fetch copies to the host, the packed (K + 1, N)
+    int32 result, is laid out with the stream axis minor — N streams
+    across the lanes, not 12 classes padded to 128 — for the frame and
+    raw-audio ticks and the scanned window."""
+    srv = StreamingKWSServer(_pipe(norm_stats, "integer"), params,
+                             max_streams=N_STREAMS)
+    k = srv.pipeline.config.gru.num_classes
+    for fn, raw_audio, window in ((srv._tick_fv, False, None),
+                                  (srv._tick_audio, True, None),
+                                  (srv._run_fv, False, WINDOW)):
+        with force_dispatch("pallas"):
+            lowered = fn.lower(
+                *_operands(srv, raw_audio, one_chip, window=window)
+            )
+            compiled = lowered.compile()
+        shape = lowered.out_info[1].shape
+        assert shape[-2:] == (k + 1, N_STREAMS)
+        minor = compiled.output_formats[1].layout.major_to_minor[-1]
+        assert minor == len(shape) - 1, compiled.output_formats[1]
 
 
 @pytest.mark.parametrize("classifier", ["float"])

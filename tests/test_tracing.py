@@ -8,13 +8,15 @@ number>``; the tick program names its stages with `jax.named_scope`
 (``kws_*``). This suite records CPU profiles of a pipelined server and
 checks what a benchmark reduction relies on:
 
-  * one ``kws.server.tick_call``, ``kws.server.own_copy`` and
+  * one ``kws.server.tick_call``, ``kws.server.prefetch`` and
     ``kws.handle.fetch`` per dispatched tick, each with the right
     ``tick`` stat, nested as documented, with ``kws.server.compile``
     only on a dispatch that traced a new (program, shape) — with
     metrics off, since tracing does not depend on a registry;
   * with a registry, the dispatch and fetch histograms observe the
-    very intervals of the matching spans;
+    very intervals of the matching spans, and
+    ``kws_serve_d2h_transfers_total`` counts one transfer per dispatch,
+    tick or window;
   * the compiled tick's HLO carries every ``kws_*`` scope for the
     integer, cascaded and mesh-sharded servers, and the persistent
     compilation cache never hands back a program with another
@@ -73,11 +75,11 @@ def _server(norm_stats, params, **kw):
     return srv
 
 
-def _drive(srv, n_ticks=N_TICKS, seed=0):
+def _drive(srv, n_ticks=N_TICKS, seed=0, window=1):
     """n_ticks FV_Norm ticks through a depth-2 pipelined ingress."""
     dim = srv.pipeline.config.fex.num_channels
     rng = np.random.default_rng(seed)
-    ing = PipelinedIngress(srv, dim, depth=2)
+    ing = PipelinedIngress(srv, dim, depth=2, window=window)
     for _ in range(n_ticks):
         slab, mask = ing.stage()
         slab[:] = rng.standard_normal(slab.shape) * 0.05
@@ -130,7 +132,7 @@ def test_spans_per_dispatched_tick(tmp_path, norm_stats, params):
     assert srv.dispatch_seq == N_TICKS
     for name in ("kws.ingress.stage", "kws.ingress.commit",
                  "kws.server.dispatch", "kws.server.tick_call",
-                 "kws.server.own_copy", "kws.handle.fetch",
+                 "kws.server.prefetch", "kws.handle.fetch",
                  "kws.handle.wait", "kws.handle.d2h"):
         got = _by_tick(spans, name)
         assert sorted(got) == ticks, name
@@ -143,7 +145,7 @@ def test_spans_per_dispatched_tick(tmp_path, norm_stats, params):
         "kws.ingress.reuse_wait": "kws.ingress.stage",
         "kws.server.dispatch": "kws.ingress.commit",
         "kws.server.tick_call": "kws.server.dispatch",
-        "kws.server.own_copy": "kws.server.dispatch",
+        "kws.server.prefetch": "kws.server.dispatch",
         "kws.server.compile": "kws.server.dispatch",
         "kws.handle.wait": "kws.handle.fetch",
         "kws.handle.d2h": "kws.handle.fetch",
@@ -185,9 +187,23 @@ def test_histograms_observe_the_spans(tmp_path, norm_stats, params):
     assert list(tr.marks) == ["stage", "commit", "dispatch", "retire"]
 
 
+@pytest.mark.parametrize("window", [1, 3], ids=["tick", "window"])
+def test_one_transfer_per_dispatch(norm_stats, params, window):
+    """Each dispatch, a tick or a scanned window, is fetched in exactly
+    one device-to-host transfer: its packed result."""
+    srv = _server(norm_stats, params, metrics=True)
+    transfers = srv.metrics.counter("kws_serve_d2h_transfers_total")
+    for n_ticks in (1, N_TICKS):
+        before, seq = transfers.value, srv.dispatch_seq
+        _drive(srv, n_ticks=n_ticks, window=window)
+        dispatches = srv.dispatch_seq - seq
+        assert dispatches == -(-n_ticks // window)
+        assert transfers.value - before == dispatches
+
+
 _SCOPES = ("kws_classifier", "kws_gru0_gemm", "kws_gru0_gates",
            "kws_gru0_update", "kws_gru1_gemm", "kws_gru1_gates",
-           "kws_gru1_update", "kws_head", "kws_smooth")
+           "kws_gru1_update", "kws_head", "kws_smooth", "kws_pack")
 
 
 @pytest.mark.parametrize("kind", ["integer", "cascade", "mesh", "audio"])
