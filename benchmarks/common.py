@@ -175,10 +175,6 @@ def percentile_stats(latencies_s) -> Dict[str, float]:
 
       backend        jax backend the sweep ran on ("cpu" / "tpu" / ...)
       frontend       registered FeatureFrontend of the benched pipeline
-      tick_impl      requested tick implementation for the sweep's
-                     non-legacy servers (--tick-impl: "auto" / "xla" /
-                     "fused-pallas" / "fused-interpret"); each row
-                     records what "auto" resolved to
       classifiers    registered ClassifierBackend keys the sweep covered
       theta          ΔGRU threshold (Q6.8 value units) the delta rows
                      ran at (--theta; dense rows are unaffected)
@@ -215,17 +211,6 @@ def percentile_stats(latencies_s) -> Dict[str, float]:
                        (isolates serving-path overhead), "audio" = raw
                        16 ms hops (adds the frontend filter scan, a
                        cost shared by every mode)
-        tick_impl      resolved tick implementation the row's server
-                       ran ("xla" = one fused XLA program,
-                       "fused-pallas" = the whole tick as ONE Pallas
-                       megakernel over stream blocks,
-                       "fused-interpret" = the same kernel body under
-                       the Pallas interpreter); None for the legacy
-                       path, which predates tick_impl
-        tick_dispatch  kernel dispatch tier of the row's ticks ("xla" /
-                       "pallas" / "interpret" —
-                       `repro.kernels.dispatch` naming); None for
-                       legacy rows
         jax_backend    jax backend the row ran on (repeated per row so
                        rows merged across artifacts stay attributable)
         devices        device count the row ran on; > 1 means the slot

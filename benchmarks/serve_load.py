@@ -58,17 +58,8 @@ legacy baseline has no gate, so fused-vs-legacy is not
 apples-to-apples there — the default (no ``--cascade``) sweep keeps
 the claim unchanged.
 
-Tick implementation (``--tick-impl``, default "auto"): every
-non-legacy server is built with the chosen
-`StreamingKWSServer(tick_impl=...)` — "xla" (one fused XLA program),
-"fused-pallas" (the whole tick as ONE Pallas megakernel over stream
-blocks, TPU), or "fused-interpret" (the same kernel body under the
-Pallas interpreter; CPU-testable but interpreter-slow, so only for
-correctness-shaped sweeps). Every row records the resolved
-``tick_impl``, the kernel dispatch tier it ran (``tick_dispatch``:
-"xla" / "pallas" / "interpret"), and the jax backend
-(``jax_backend``), so artifacts from different platforms stay
-comparable.
+Every row records the jax backend it ran on (``jax_backend``), so
+artifacts from different platforms stay comparable.
 
 Devices (``--devices``, default "auto"): every row records the device
 count it ran on. Counts > 1 build the server on a ``("stream",)`` mesh
@@ -114,7 +105,6 @@ BENCH artifact.
 
   PYTHONPATH=src python -m benchmarks.serve_load [--classifier all]
       [--devices auto|1|1,2,...] [--theta 0.25]
-      [--tick-impl auto|xla|fused-pallas|fused-interpret]
       [--cascade [--wake-threshold 0.15]] [--fail-on-slo]
 """
 
@@ -289,7 +279,7 @@ def _timed(fn):
 
 
 def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
-                devices=1, tick_impl="auto"):
+                devices=1):
     n_active = max(1, int(round(max_streams * occupancy)))
     slabs, dicts = _traffic(pipe, max_streams, n_active, kind)
     n_var = len(slabs)
@@ -315,7 +305,7 @@ def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
         # _bench_metrics_overhead — < 5% of a fused tick.
         srv = StreamingKWSServer(
             pipe, params, max_streams=max_streams, devices=devices,
-            tick_impl=tick_impl, metrics=True,
+            metrics=True,
         )
         for sid in range(n_active):
             srv.open_stream(sid)
@@ -328,7 +318,7 @@ def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
     elif mode == "pipelined":
         srv = StreamingKWSServer(
             pipe, params, max_streams=max_streams, devices=devices,
-            tick_impl=tick_impl, metrics=True,
+            metrics=True,
         )
         for sid in range(n_active):
             srv.open_stream(sid)
@@ -367,7 +357,6 @@ def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
     elif mode == "scan":
         srv = StreamingKWSServer(
             pipe, params, max_streams=max_streams, devices=devices,
-            tick_impl=tick_impl,
         )
         for sid in range(n_active):
             srv.open_stream(sid)
@@ -425,17 +414,8 @@ def _bench_mode(mode, kind, pipe, params, max_streams, occupancy, n_ticks,
         "classifier": pipe.config.classifier_key,
         "mode": mode,
         "kind": kind,
-        # tick implementation the row's server resolved to, the kernel
-        # dispatch tier its ticks ran, and the jax backend underneath —
-        # None/None for the legacy path (predates tick_impl); recorded
-        # per row so artifacts from different platforms compare
-        "tick_impl": (
-            srv.tick_impl if isinstance(srv, StreamingKWSServer) else None
-        ),
-        "tick_dispatch": (
-            srv.tick_dispatch
-            if isinstance(srv, StreamingKWSServer) else None
-        ),
+        # the jax backend underneath, recorded per row so artifacts
+        # from different platforms compare
         "jax_backend": jax.default_backend(),
         "devices": devices,
         "max_streams": max_streams,
@@ -546,8 +526,7 @@ def _auto_devices():
 
 
 def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
-        cascade=False, wake_threshold=0.15, fail_on_slo=False,
-        tick_impl="auto"):
+        cascade=False, wake_threshold=0.15, fail_on_slo=False):
     casc = (
         CascadeConfig(wake_threshold=wake_threshold) if cascade else None
     )
@@ -612,7 +591,7 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
                         for d in devs:
                             r = _bench_mode(
                                 mode, kind, pipe, params, ms, occ,
-                                N_TICKS, devices=d, tick_impl=tick_impl,
+                                N_TICKS, devices=d,
                             )
                             results.append(r)
                             sp = (
@@ -757,10 +736,6 @@ def run(classifiers=("qat", "integer", "delta"), devices=None, theta=0.25,
     payload = {
         "backend": jax.default_backend(),
         "frontend": frontend,
-        # requested tick implementation for the sweep's rows (each row
-        # additionally records what it resolved to and the dispatch
-        # tier it ran)
-        "tick_impl": tick_impl,
         "classifiers": list(classifiers),
         # ΔGRU threshold the delta rows ran at (per-row "theta" repeats
         # it; dense rows carry theta=None and sparsity=1.0)
@@ -905,16 +880,6 @@ if __name__ == "__main__":
              "for the async ingress and observability layers",
     )
     ap.add_argument(
-        "--tick-impl", default="auto",
-        choices=["auto", "xla", "fused-pallas", "fused-interpret"],
-        help="tick implementation for every non-legacy server "
-             "(StreamingKWSServer(tick_impl=...)): 'auto' = "
-             "xla on every platform; 'fused-interpret' "
-             "runs the megakernel under the Pallas interpreter "
-             "(correctness-shaped, interpreter-slow on CPU). Rows "
-             "record the resolved impl + dispatch tier",
-    )
-    ap.add_argument(
         "--theta", type=float, default=0.25,
         help="ΔGRU delta threshold (Q6.8 value units, applied to both "
              "input and hidden deltas of every layer) for the "
@@ -933,5 +898,4 @@ if __name__ == "__main__":
         cascade=args.cascade,
         wake_threshold=args.wake_threshold,
         fail_on_slo=args.fail_on_slo,
-        tick_impl=args.tick_impl,
     )

@@ -5,10 +5,10 @@
 
 One chip: for the qat, integer, delta (θ=0.15) and a cascaded integer
 pipeline at the paper's width (16-channel FEx, 2×GRU(48), 12 classes),
-`StreamingKWSServer` opens 1024 streams with its default `tick_impl` and
-kernel dispatch and serves raw 16 ms audio hops: 8 ticks through
+`StreamingKWSServer` opens 1024 streams with its default kernel dispatch
+and serves raw 16 ms audio hops: 8 ticks through
 `step_batch`, one `run_batch` window and one `PipelinedIngress` window.
-The same ticks are replayed in this process through the "xla" tick on
+The same ticks are replayed in this process through the same tick on
 the host CPU device and compared within the tolerances below, each with
 its reason. Each pipeline is then served FV_Norm frames made by the CPU
 frontend from the same audio, on the TPU and on the CPU: there every
@@ -201,16 +201,15 @@ def drive(srv, slab, mask, compiles):
 def serve_on_chip(tag, pipe, params, slab, mask, compiles, errors):
     """Default server on the TPU; logs what it resolved and compiled."""
     srv = StreamingKWSServer(pipe, params, max_streams=N_STREAMS)
-    tiers = {"tick": srv.tick_dispatch}
+    intgemm = None  # the float-domain backends call no intgemm
     if pipe.config.classifier_key in ("integer", "delta-int"):
-        tiers["intgemm"] = resolve_intgemm_dispatch()
-        if tiers["intgemm"] != "pallas":
-            errors.append(f"{tag}: intgemm resolved to "
-                          f"{tiers['intgemm']!r} on TPU")
+        intgemm = resolve_intgemm_dispatch()
+        if intgemm != "pallas":
+            errors.append(f"{tag}: intgemm resolved to {intgemm!r} on TPU")
     c0, s0 = compiles.n, compiles.seconds
     sc, top, first, late = drive(srv, slab, mask, compiles)
     state = jax.device_get(srv.state)
-    log(f"  [{tag}] tick_impl={srv.tick_impl} tiers={tiers} "
+    log(f"  [{tag}] intgemm={intgemm} "
         f"compile_count={srv.compile_count} retrace_count="
         f"{srv.retrace_count} compiles={compiles.n - c0} compile_s="
         f"{compiles.seconds - s0:.2f} first_call_s="
@@ -226,13 +225,13 @@ def serve_on_chip(tag, pipe, params, slab, mask, compiles, errors):
 
 
 def replay_on_cpu(name, mu, sigma, params, slab, mask, compiles):
-    """The same ticks through the "xla" tick and reference kernels on
-    the host CPU device."""
+    """The same ticks through the reference kernels on the host CPU
+    device."""
     with jax.default_device(jax.devices("cpu")[0]), \
             force_dispatch("reference"):
         pipe = make_pipeline(name, mu, sigma)
         ref = StreamingKWSServer(pipe, jax.tree.map(np.asarray, params),
-                                 max_streams=N_STREAMS, tick_impl="xla")
+                                 max_streams=N_STREAMS)
         sc, top, _, _ = drive(ref, slab, mask, compiles)
         return sc, top, jax.device_get(ref.state)
 

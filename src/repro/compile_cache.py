@@ -35,7 +35,7 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_compilation_cache_dir", path)
     # key entries on the programs' metadata too: a program cached by a
-    # checkout with other named scopes (`tick_reference`'s kws_*) would
+    # checkout with other named scopes (`serve_tick`'s kws_*) would
     # otherwise come back with that checkout's op names in its profile
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # the serving programs compile in about a second each; cache them all

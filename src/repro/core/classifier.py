@@ -39,7 +39,7 @@ post-processing, and logit codes dequantize to exact float32).
 
 Hidden state is backend-owned: `init_states` returns float32 leaves
 for "float"/"qat" and int32 code leaves for "integer", and the fused
-serving tick (`repro.serving.serve_loop.ServerState`) carries whichever
+serving tick (`repro.serving.tick.ServerState`) carries whichever
 it is through donation without caring.
 """
 

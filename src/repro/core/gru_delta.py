@@ -40,7 +40,7 @@ Per layer, the delta state carries:
 Per step, with θ in Q6.8 code units (`DeltaConfig`):
 
   Δx = x - x_ref;  fire = |Δx| > θ_x;  Δx[~fire] = 0
-  x_ref += Δx;     acc_x += Δx · W_i          (only fired columns cost)
+  x_ref += Δx;     acc_x += Δx · W_i          (only fired columns count)
   gi = quantize(acc_x + b_i)                  (the dense path's
                                                quantize(x·W_i + b_i))
   ... and the same for Δh against h_ref / acc_h / W_h; the gates, the
@@ -59,8 +59,10 @@ sums) whose in-range sums and products are exact in both int32 and
 float32, so adding increments in a different order changes nothing.
 
 The skipped/total counters count DELTA-ELIGIBLE work only — the GRU
-matmul lanes a ΔGRU can skip (each skipped input component saves a
-3H-wide weight column). Bias adds and the dense FC head are excluded
+matmul lanes a ΔGRU can skip (each skipped input component is a
+3H-wide weight column a sparse datapath need not read). Every lowering
+here still computes the dense Δ·W product: the skipped columns are
+counted, not saved. Bias adds and the dense FC head are excluded
 from the counters; `effective_mac_fraction` converts columns to MACs
 per layer and folds the always-dense FC back in, so the reported
 fraction covers the whole classifier.
@@ -252,38 +254,32 @@ def delta_gru_cell(
     x: jnp.ndarray,
     config: GRUConfig,
     thetas: Tuple[int, int],
-    matmul=None,
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """One ΔGRU step, QAT float domain: x (B, I) -> (new state, h' (B, H)).
 
     The gate math after the partial sums is verbatim `gru.gru_cell`
     (quantized gate outputs, ROM-faithful ordering); only the way the
     two matmul preactivations are produced differs — incrementally from
-    the thresholded deltas instead of densely from x and h.
-
-    ``matmul`` overrides how a Δ·W contribution is evaluated (default:
-    dense `gru.qdot`). The fused-tick megakernel passes its gather-
-    compacted sparse product here (`repro.kernels.tick_fused`), which
-    multiplies only the firing columns — bit-identical by the grid
-    argument above, but with work proportional to the fire count.
+    the thresholded deltas instead of densely from x and h. The Δ·W
+    products are dense `gru.qdot` calls: a skipped column is a zero
+    row of Δ, counted in ``skipped`` but still multiplied.
     """
     aspec = config.act_spec
     w_i, w_h, b_i, b_h = _layer_weights(layer, config.weight_spec)
     tx, th = thetas
     scale = quant.ACT_Q6_8.scale
-    mm = qdot if matmul is None else matmul
 
     dx = x - st["x_ref"]
     fire_x = jnp.abs(dx) > tx * scale
     dx = jnp.where(fire_x, dx, 0.0)
     x_ref = st["x_ref"] + dx
-    acc_x = st["acc_x"] + mm(dx, w_i)
+    acc_x = st["acc_x"] + qdot(dx, w_i)
 
     dh = st["h"] - st["h_ref"]
     fire_h = jnp.abs(dh) > th * scale
     dh = jnp.where(fire_h, dh, 0.0)
     h_ref = st["h_ref"] + dh
-    acc_h = st["acc_h"] + mm(dh, w_h)
+    acc_h = st["acc_h"] + qdot(dh, w_h)
 
     gi = _maybe_q(acc_x + b_i, aspec)  # (B, 3H)
     gh = _maybe_q(acc_h + b_h, aspec)
@@ -321,7 +317,6 @@ def delta_classifier_step(
     fv_t: jnp.ndarray,
     config: GRUConfig,
     thetas: Tuple[Tuple[int, int], ...],
-    matmul=None,
 ) -> Tuple[List[Dict[str, jnp.ndarray]], jnp.ndarray]:
     """Streaming ΔGRU step: one frame (B, C) -> (new states, (B, K)).
 
@@ -330,12 +325,11 @@ def delta_classifier_step(
     stay on the grid or the partial sums stop telescoping exactly —
     and it keeps "delta" and "delta-int" in bit-agreement for any
     input, mirroring the integer backend's entry quantization.
-    ``matmul`` threads through to every `delta_gru_cell`.
     """
     new_states = []
     x = quant.fake_quant(fv_t, config.act_spec)
     for layer, st, t in zip(params["gru"], states, thetas):
-        st, x = delta_gru_cell(layer, st, x, config, t, matmul=matmul)
+        st, x = delta_gru_cell(layer, st, x, config, t)
         new_states.append(st)
     return new_states, _fc_logits(params, x, config)
 
@@ -376,37 +370,30 @@ def int_delta_gru_cell(
     x: jnp.ndarray,
     config: GRUConfig,
     thetas: Tuple[int, int],
-    matmul=None,
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """One ΔGRU step on codes: x (B, I) int32 Q6.8 -> (state, h' codes).
 
     Gate math after the partial sums is verbatim `gru_int.int_gru_cell`;
     the frac-15 partial sums live in the persistent int32 accumulators
     (the DeltaKWS per-neuron partial-sum memory) instead of being
-    recomputed densely.
-
-    ``matmul`` overrides how a Δ·W contribution is evaluated (default:
-    the saturating-int24 `intgemm` kernel). The fused-tick megakernel
-    passes its gather-compacted sparse product (`repro.kernels.
-    tick_fused`), which multiplies only the firing columns and applies
-    the same final int24 saturation — identical int32 codes, work
-    proportional to the fire count.
+    recomputed densely. The Δ·W products go through the dense
+    saturating-int24 `intgemm` kernel: a skipped column is a zero row
+    of Δ, counted in ``skipped`` but still multiplied.
     """
     del config  # geometry is carried by the code arrays themselves
     tx, th = thetas
-    mm = intgemm if matmul is None else matmul
 
     dx = x - st["x_ref"]
     fire_x = jnp.abs(dx) > tx
     dx = jnp.where(fire_x, dx, 0)
     x_ref = st["x_ref"] + dx
-    acc_x = st["acc_x"] + mm(dx, layer["w_i"])
+    acc_x = st["acc_x"] + intgemm(dx, layer["w_i"])
 
     dh = st["h"] - st["h_ref"]
     fire_h = jnp.abs(dh) > th
     dh = jnp.where(fire_h, dh, 0)
     h_ref = st["h_ref"] + dh
-    acc_h = st["acc_h"] + mm(dh, layer["w_h"])
+    acc_h = st["acc_h"] + intgemm(dh, layer["w_h"])
 
     gi = quant.clip_act_codes(
         quant.round_shift_even(acc_x + layer["b_i"], _ACC_SHIFT)
@@ -445,14 +432,12 @@ def int_delta_classifier_step(
     fv_t: jnp.ndarray,
     config: GRUConfig,
     thetas: Tuple[Tuple[int, int], ...],
-    matmul=None,
 ) -> Tuple[List[Dict[str, jnp.ndarray]], jnp.ndarray]:
-    """Streaming ΔGRU step on codes: one frame (B, C) -> (states, (B, K)).
-    ``matmul`` threads through to every `int_delta_gru_cell`."""
+    """Streaming ΔGRU step on codes: one frame (B, C) -> (states, (B, K))."""
     new_states = []
     x = fv_t
     for layer, st, t in zip(qparams.gru, states, thetas):
-        st, x = int_delta_gru_cell(layer, st, x, config, t, matmul=matmul)
+        st, x = int_delta_gru_cell(layer, st, x, config, t)
         new_states.append(st)
     return new_states, _int_fc_logits(qparams, x)
 

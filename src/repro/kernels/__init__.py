@@ -5,10 +5,6 @@ interpret mode on CPU; BlockSpec layouts target TPU VMEM/MXU).
   gru        — weights-resident GRU sequence (the IC's WMEM insight)
   intgemm    — int16 x int8 -> saturating-int24 matmul (HPE datapath)
   tdc        — SRO DeltaSigma TDC + XOR diff + CIC decimation
-  tick_fused — the WHOLE 16 ms serving tick (frontend + cascade gate +
-               ΔGRU/GRU layers + FC + softmax + smoothing) as one
-               megakernel over stream blocks, with a gather-only ΔGRU
-               column update so temporal sparsity becomes wall-clock
   wkv6       — state-resident RWKV6 recurrence (the §Perf cell-C lever)
 
 Dispatch-tier selection (pallas on TPU / interpret / reference, the
@@ -28,15 +24,6 @@ from repro.kernels.intgemm import intgemm, intgemm_ref
 from repro.kernels.tdc import tdc_counts, tdc_counts_ref
 from repro.kernels.wkv6 import wkv6, wkv6_ref
 
-# tick_fused traces classifier backends (which call intgemm) inside its
-# kernel body, so it must import LAST: everything it reroutes through
-# `force_dispatch("reference")` is already bound above.
-from repro.kernels.tick_fused import (
-    tick_fused,
-    tick_fused_pallas,
-    tick_reference,
-)
-
 __all__ = [
     "DISPATCH_TIERS", "dispatch_override", "force_dispatch",
     "resolve_dispatch",
@@ -44,6 +31,5 @@ __all__ = [
     "gru_sequence", "gru_sequence_ref",
     "intgemm", "intgemm_ref",
     "tdc_counts", "tdc_counts_ref",
-    "tick_fused", "tick_fused_pallas", "tick_reference",
     "wkv6", "wkv6_ref",
 ]

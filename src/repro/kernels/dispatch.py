@@ -11,19 +11,14 @@ Every kernel in `repro.kernels` ships three equivalent implementations:
 
 Before this module each kernel's ``ops.py`` carried its own copy of the
 same three decisions; they are centralized here so `tdc` / `intgemm` /
-`gru` / `fex_fused` / `tick_fused` resolve identically:
+`gru` / `fex_fused` resolve identically:
 
   1. `resolve_dispatch` — map ("auto" | explicit tier, legacy
      ``interpret=`` flag) to a concrete tier for this jax backend.
   2. `force_dispatch` — a thread-local override consulted before
-     everything else. The fused-tick megakernel body
-     (`repro.kernels.tick_fused`) traces the whole serving tick —
-     including classifier backends that themselves call `intgemm` —
-     INSIDE a `pallas_call`; a `pallas_call` cannot nest, so the
-     megakernel activates ``force_dispatch("reference")`` while tracing
-     its body and every nested kernel entry point resolves to its
-     pure-jnp reference (bit-identical by contract, so the megakernel's
-     output is unchanged).
+     everything else: it pins every kernel entry point traced inside
+     it to one tier (the v5e compile tests lower the Pallas tier from
+     a CPU host; `chip_smoke.py` replays on the reference tier).
 """
 
 from __future__ import annotations

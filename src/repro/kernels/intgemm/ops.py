@@ -11,11 +11,7 @@
                     the kernel for all in-range inputs).
 
 Tier selection, the legacy ``interpret=`` flag and the `force_dispatch`
-override are the shared `repro.kernels.dispatch` machinery. The
-override matters here: `intgemm` is traced inside the fused-tick
-megakernel's body by the integer/delta-int classifier backends, where
-`force_dispatch("reference")` reroutes it to `intgemm_ref` (a
-`pallas_call` cannot nest).
+override are the shared `repro.kernels.dispatch` machinery.
 """
 
 from __future__ import annotations

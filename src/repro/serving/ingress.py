@@ -1,6 +1,6 @@
 """Async double-buffered ingress for the streaming KWS server.
 
-The fused tick (`repro.serving.serve_loop._fused_tick`) is one device
+The serving tick (`repro.serving.tick.serve_tick`) is one device
 program, but the live `step_batch` path around it is synchronous: build
 the slab, dispatch, then BLOCK on the device-to-host score fetch before
 the next tick may even be assembled. On an async-dispatch backend the

@@ -31,15 +31,16 @@ gate (`CascadeConfig.always_on()`) is bit-identical to no cascade for
 every backend. This is the serve-side example driver
 (examples/serve_streaming.py).
 
-The whole per-tick device program is ONE fused jit (`_fused_tick`):
-frontend feature extraction, the batched GRU step, softmax, and
-exponential score smoothing run back-to-back on-device over donated
-state buffers, under a per-stream submitted mask. State — GRU hidden
-states, frontend carry, smoothed scores — lives in a single
-`ServerState` pytree; an idle stream's slice of every buffer is
-bit-identical across a tick it did not submit to (temporal sparsity,
-the DeltaKWS deployment contract). `open_stream`/`close_stream` recycle
-slots from a free list, zeroing only the reused slot, and
+The whole per-tick device program is ONE fused jit (`_fused_tick`, whose
+math is `repro.serving.tick.serve_tick`): frontend feature extraction,
+the batched GRU step, softmax, and exponential score smoothing run
+back-to-back on-device over donated state buffers, under a per-stream
+submitted mask. State — GRU hidden states, frontend carry, smoothed
+scores — lives in a single `ServerState` pytree; an idle stream's
+slice of every buffer is bit-identical across a tick it did not submit
+to (temporal sparsity, the DeltaKWS deployment contract).
+`open_stream`/`close_stream` recycle slots from a free list, zeroing
+only the reused slot, and
 `StreamingKWSServer.run` replays buffered audio through a `lax.scan`
 over the same tick body for offline-throughput serving.
 
@@ -66,7 +67,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,12 +75,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.tick_fused import (
-    mosaic_unsupported,
-    over_stream_shards,
-    tick_fused,
-    tick_reference,
-)
 from repro.serving import cascade as cascade_lib
 
 from repro.distributed.sharding import (
@@ -99,6 +94,7 @@ from repro.models.registry import get_backbone
 from repro.serving.autoscale import StreamRouter
 from repro.serving.ingress import TickHandle, pack_result
 from repro.serving.metrics import MetricsRegistry, span
+from repro.serving.tick import ServerState, over_stream_shards, serve_tick
 
 Pytree = Any
 
@@ -214,48 +210,6 @@ def lower_prefill(arch_cfg, rules: ShardingRules, shape_spec):
 # Streaming KWS serving (the paper's own deployment shape)
 # --------------------------------------------------------------------------
 
-@dataclasses.dataclass(frozen=True)
-class ServerState:
-    """All per-slot device state of a `StreamingKWSServer`, as one pytree.
-
-    gru    — per-layer classifier state, owned by the backend: a
-             (max_streams, H) float32 hidden state per layer for
-             float/qat, int32 Q6.8 codes for "integer", and for the
-             ΔGRU backends a per-layer dict {h, x_ref, h_ref, acc_x,
-             acc_h, skipped, total} of (max_streams, ...) leaves
-             (masking, donation, slot resets, and the stream mesh are
-             structure- and dtype-agnostic; all-zeros is every
-             backend's valid fresh state).
-    carry  — frontend streaming carry (filter / SRO-phase state), a dict
-             of (max_streams, ...) arrays from `streaming_features_init`.
-    scores — exponentially smoothed posteriors, (max_streams, K).
-    det    — stage-1 wake-gate state for a cascaded pipeline
-             (`repro.serving.cascade.init_state`: per-stream awake
-             latch, hangover countdown, woken/ticks counters, all
-             (max_streams,) leaves; all-zeros is the valid fresh
-             state). None when `pipeline.config.cascade` is None —
-             a None leaf vanishes from the pytree, so a non-cascaded
-             server keeps the exact pre-cascade state structure and
-             device programs.
-
-    The pytree crosses jit as a single donated argument: every tick
-    consumes the old state buffers and writes the new ones in place
-    (donation), so steady-state serving allocates nothing per tick.
-    """
-
-    gru: Tuple[jnp.ndarray, ...]
-    carry: Any
-    scores: jnp.ndarray
-    det: Any = None
-
-
-jax.tree_util.register_dataclass(
-    ServerState,
-    data_fields=["gru", "carry", "scores", "det"],
-    meta_fields=[],
-)
-
-
 # kept importable for API compatibility with the pre-fused server
 @dataclasses.dataclass
 class StreamState:
@@ -263,62 +217,20 @@ class StreamState:
     scores: Optional[np.ndarray] = None  # smoothed class scores
 
 
-# tick_impl -> the kernel layer's dispatch tier (ISSUE: the serving API
-# speaks deployment names, the kernel layer speaks tiers)
-_TICK_IMPLS = ("auto", "xla", "fused-pallas", "fused-interpret")
-_TICK_DISPATCH = {
-    "xla": "xla", "fused-pallas": "pallas", "fused-interpret": "interpret",
-}
-
-
 def _fused_tick(pipeline, raw_audio, params, state: ServerState, inp,
-                mask, frontend_state, smoothing, *, tick_impl="xla",
-                mesh=None):
-    """One fully fused serving tick, traced as a single device program.
-
-    The tick MATH — frontend feature frame, stage-1 cascade wake gate,
-    classifier step, softmax, smoothing, masked state advance — lives
-    in `repro.kernels.tick_fused.tick_reference` (moved there verbatim
-    so the megakernel can re-run it per stream block); this wrapper
-    owns only the `ServerState` packing and the implementation choice:
-
-      tick_impl="xla"             one fused XLA program (what "auto"
-                                  picks; exactly the pre-kernel tick)
-      tick_impl="fused-pallas"    the whole tick as ONE `pallas_call`
-                                  over stream blocks with the ΔGRU
-                                  gather path (TPU)
-      tick_impl="fused-interpret" the same megakernel body under the
-                                  Pallas interpreter (CPU-testable)
-
-    All three are bit-identical for every classifier backend (tests/
-    test_tick_fused.py). With a stream ``mesh`` every choice runs once
-    per shard-local slab under a `shard_map` (`over_stream_shards`):
-    GSPMD cannot partition a `pallas_call`, and the xla tick holds the
-    intgemm kernels on TPU.
-    """
-    state4 = (state.gru, state.carry, state.scores, state.det)
-    if tick_impl == "xla":
-        tick = over_stream_shards(
-            functools.partial(tick_reference, pipeline, raw_audio), mesh
-        )
-        (gru, carry, scores, det), out_scores, top = tick(
-            params, state4, inp, mask, frontend_state, smoothing,
-        )
-    else:
-        (gru, carry, scores, det), out_scores, top = tick_fused(
-            pipeline, raw_audio, params, state4, inp, mask,
-            frontend_state, smoothing,
-            dispatch=_TICK_DISPATCH[tick_impl], mesh=mesh,
-        )
-    return (
-        ServerState(gru=gru, carry=carry, scores=scores, det=det),
-        out_scores,
-        top,
+                mask, frontend_state, smoothing, *, mesh=None):
+    """One fully fused serving tick, traced as a single device program:
+    `repro.serving.tick.serve_tick`, run once per shard-local slab under
+    a `shard_map` when the server has a stream ``mesh``
+    (`over_stream_shards`). Returns ``(new_state, scores, top)``."""
+    tick = over_stream_shards(
+        functools.partial(serve_tick, pipeline, raw_audio), mesh
     )
+    return tick(params, state, inp, mask, frontend_state, smoothing)
 
 
 def _tick(pipeline, raw_audio, params, state: ServerState, inp, mask,
-          frontend_state, smoothing, *, tick_impl="xla", mesh=None):
+          frontend_state, smoothing, *, mesh=None):
     """The live tick's device program: `_fused_tick`, with its (scores,
     top) packed into the one lane-dense buffer the host fetches
     (`repro.serving.ingress.pack_result`). The buffer is a fresh value
@@ -326,7 +238,7 @@ def _tick(pipeline, raw_audio, params, state: ServerState, inp, mask,
     handle holding it outlives every later tick's donation."""
     state, scores, top = _fused_tick(
         pipeline, raw_audio, params, state, inp, mask, frontend_state,
-        smoothing, tick_impl=tick_impl, mesh=mesh,
+        smoothing, mesh=mesh,
     )
     return state, pack_result(scores, top)
 
@@ -376,17 +288,6 @@ class StreamingKWSServer:
     server. ``devices=None`` with a single visible device (and a
     size-1 mesh) falls back to the pre-sharding single-device path.
 
-    Tick implementation: ``tick_impl=`` selects how the per-tick device
-    program is built — ``"xla"`` (one fused XLA program, the historical
-    tick), ``"fused-pallas"`` (the whole tick as ONE Pallas megakernel
-    over stream blocks with the ΔGRU gather path — temporal sparsity
-    becomes wall-clock speed), ``"fused-interpret"`` (the megakernel
-    under the Pallas interpreter, for CPU CI), or ``"auto"`` (default:
-    xla on every platform — Mosaic cannot lower the megakernel for most
-    pipelines, see `repro.kernels.tick_fused.mosaic_unsupported`). The
-    resolved choice and its kernel dispatch tier are exposed as
-    `srv.tick_impl` / `srv.tick_dispatch`.
-
     Observability: ``metrics=`` takes a
     `repro.serving.metrics.MetricsRegistry` (or ``True`` for a fresh
     default one, exposed as `srv.metrics`) and instruments the server:
@@ -414,35 +315,9 @@ class StreamingKWSServer:
 
     def __init__(self, pipeline, params, max_streams: int = 256,
                  smoothing: float = 0.7, state=None, mesh=None,
-                 devices: Optional[int] = None, tick_impl: str = "auto",
-                 metrics=None):
+                 devices: Optional[int] = None, metrics=None):
         if mesh is not None and devices is not None:
             raise ValueError("pass mesh= or devices=, not both")
-        if tick_impl not in _TICK_IMPLS:
-            raise ValueError(
-                f"tick_impl must be one of {_TICK_IMPLS}; got "
-                f"{tick_impl!r}"
-            )
-        if tick_impl == "auto":
-            # the fused-XLA tick is the one program that compiles for
-            # every backend and both input kinds on every platform; the
-            # megakernel stays an explicit choice (see
-            # `repro.kernels.tick_fused.mosaic_unsupported`)
-            tick_impl = "xla"
-        if tick_impl == "fused-pallas":
-            # FV_Norm is the megakernel's widest reach; a pipeline it
-            # cannot serve there it cannot serve at all (raw-audio ticks
-            # are refused per tick by `tick_fused`)
-            reason = mosaic_unsupported(pipeline, raw_audio=False)
-            if reason is not None:
-                raise ValueError(
-                    f"tick_impl='fused-pallas' cannot serve this "
-                    f"pipeline: {reason}; use tick_impl='xla'"
-                )
-        self.tick_impl = tick_impl
-        # the kernel dispatch tier the ticks will actually run
-        # ("xla" = no pallas_call at all) — benchmarks record this
-        self.tick_dispatch = _TICK_DISPATCH[tick_impl]
         if mesh is None and devices is not None:
             # stream_mesh is the single count-vs-visible validator; the
             # size-1 fallback below then strips a one-device mesh
@@ -618,7 +493,6 @@ class StreamingKWSServer:
                 "compile_programs",
                 n_devices=self.n_devices,
                 max_streams=self.max_streams,
-                tick_impl=self.tick_impl,
             )
         mesh, pipeline = self.mesh, self.pipeline
         if mesh is None:
@@ -657,22 +531,19 @@ class StreamingKWSServer:
                 in_shardings=(st_sh, scalar),
                 out_shardings=st_sh,
             )
-        impl_kw = dict(tick_impl=self.tick_impl, mesh=mesh)
         self._tick_audio = jax.jit(
-            functools.partial(_tick, pipeline, True, **impl_kw),
-            **tick_kw,
+            functools.partial(_tick, pipeline, True, mesh=mesh), **tick_kw
         )
         self._tick_fv = jax.jit(
-            functools.partial(_tick, pipeline, False, **impl_kw),
-            **tick_kw,
+            functools.partial(_tick, pipeline, False, mesh=mesh), **tick_kw
         )
         self._reset = jax.jit(_reset_slot, **reset_kw)
         self._run_audio = jax.jit(
-            functools.partial(_run_scan, pipeline, True, **impl_kw),
+            functools.partial(_run_scan, pipeline, True, mesh=mesh),
             **run_kw,
         )
         self._run_fv = jax.jit(
-            functools.partial(_run_scan, pipeline, False, **impl_kw),
+            functools.partial(_run_scan, pipeline, False, mesh=mesh),
             **run_kw,
         )
 
@@ -727,13 +598,12 @@ class StreamingKWSServer:
     def metrics_snapshot(self) -> Dict[str, Any]:
         """One JSON-able dict of everything observable about the server.
 
-        ``server`` block: identity (tick_impl / dispatch tier / mesh
-        size), capacity and occupancy, retrace/compile counts, and the
-        per-backend telemetry rollups — mean `sparsity` (ΔGRU
-        effective-MAC fraction) and `wake_rate` (cascade duty cycle)
-        over the OPEN slots (None with no streams open). Those two
-        read device state (a host sync), which is fine here: snapshots
-        happen off the tick path. With `metrics=` enabled the registry
+        ``server`` block: mesh size, capacity and occupancy,
+        retrace/compile counts, and the per-backend telemetry rollups —
+        mean `sparsity` (ΔGRU effective-MAC fraction) and `wake_rate`
+        (cascade duty cycle) over the OPEN slots (None with no streams
+        open). Those two read device state (a host sync), which is fine
+        here: snapshots happen off the tick path. With `metrics=` enabled the registry
         snapshot (counters / gauges / histograms with percentiles,
         journal, trace span rollups) is merged in; with metrics off
         only the server block is returned.
@@ -743,8 +613,6 @@ class StreamingKWSServer:
         """
         slots = sorted(self.active.values())
         server: Dict[str, Any] = {
-            "tick_impl": self.tick_impl,
-            "tick_dispatch": self.tick_dispatch,
             "n_devices": self.n_devices,
             "max_streams": self.max_streams,
             "open_streams": len(self.active),
@@ -1326,19 +1194,17 @@ class StreamingKWSServer:
 
 
 def _run_scan(pipeline, raw_audio, params, state: ServerState, slab, mask,
-              frontend_state, smoothing, *, tick_impl="xla", mesh=None):
+              frontend_state, smoothing, *, mesh=None):
     """lax.scan of the live tick over (n_ticks, N, S|C) buffered input.
 
-    The scan body is the very `_tick` the live path jits — same
-    tick_impl, so a fused-pallas server replays its megakernel inside
-    the scan too (one kernel launch per scanned tick) — and returns
-    its packed results stacked, (n_ticks, K + 1, N)."""
+    The scan body is the very `_tick` the live path jits; the scan
+    returns its packed results stacked, (n_ticks, K + 1, N)."""
 
     def body(st, xs):
         x_t, m_t = xs
         return _tick(
             pipeline, raw_audio, params, st, x_t, m_t, frontend_state,
-            smoothing, tick_impl=tick_impl, mesh=mesh,
+            smoothing, mesh=mesh,
         )
 
     return jax.lax.scan(body, state, (slab, mask))

@@ -187,7 +187,7 @@ def shared_params():
 
 
 def _server(norm_stats, params, classifier, cascade=None, theta=0.0,
-            max_streams=4, tick_impl="auto"):
+            max_streams=4):
     pipe = KWSPipeline(
         KWSPipelineConfig(
             classifier=classifier,
@@ -196,9 +196,7 @@ def _server(norm_stats, params, classifier, cascade=None, theta=0.0,
         ),
         norm_stats=norm_stats,
     )
-    return StreamingKWSServer(
-        pipe, params, max_streams=max_streams, tick_impl=tick_impl
-    )
+    return StreamingKWSServer(pipe, params, max_streams=max_streams)
 
 
 def _assert_gru_identical(a, b):
@@ -392,13 +390,20 @@ def test_wake_telemetry_idle_freeze_and_slot_reset(
     assert srv.wake_rate[slot1] == 1.0
 
 
-def test_scan_replay_matches_live_ticks(norm_stats, shared_params):
+@pytest.mark.parametrize("classifier", ("qat", "integer", "delta",
+                                        "delta-int"))
+def test_scan_replay_matches_live_ticks(norm_stats, shared_params,
+                                        classifier):
     """The gate is exact under `lax.scan`: replaying a slab through
-    run_batch leaves scores AND detector counters bit-identical to the
-    same traffic through live step_batch ticks."""
+    run_batch leaves scores, GRU state AND detector counters
+    bit-identical to the same traffic through live step_batch ticks,
+    for each classifier backend (the ΔGRU pair at a real θ)."""
     cc = CascadeConfig(wake_threshold=0.1, hangover_frames=1)
-    live = _server(norm_stats, shared_params, "qat", cascade=cc)
-    scan = _server(norm_stats, shared_params, "qat", cascade=cc)
+    theta = 0.25 if classifier.startswith("delta") else 0.0
+    live = _server(norm_stats, shared_params, classifier, cascade=cc,
+                   theta=theta)
+    scan = _server(norm_stats, shared_params, classifier, cascade=cc,
+                   theta=theta)
     for s in (live, scan):
         for sid in range(3):
             s.open_stream(sid)
@@ -412,6 +417,7 @@ def test_scan_replay_matches_live_ticks(norm_stats, shared_params):
         live.step_batch(slab[t], mask[t])
     scan.run_batch(slab, mask)
     np.testing.assert_array_equal(live.scores, scan.scores)
+    _assert_gru_identical(live, scan)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)
@@ -447,32 +453,3 @@ def test_cascade_composes_with_delta(norm_stats, shared_params):
     assert totals_after_gate == totals_after_wake
     assert srv.sparsity[slot] == sparsity_after_wake
     assert srv.wake_rate[slot] == pytest.approx(1 / 4)
-
-
-@pytest.mark.parametrize("classifier", ("qat", "delta"))
-def test_cascaded_fused_tick_bit_identical(
-    norm_stats, shared_params, classifier
-):
-    """The megakernel tick (interpret tier) reproduces the cascaded
-    server bit for bit at a REAL wake threshold: frozen gated state,
-    score decay, and the wake telemetry all survive block slicing."""
-    casc = CascadeConfig(wake_threshold=0.1, score_decay=0.9)
-    a = _server(norm_stats, shared_params, classifier, cascade=casc,
-                theta=0.25, tick_impl="xla")
-    b = _server(norm_stats, shared_params, classifier, cascade=casc,
-                theta=0.25, tick_impl="fused-interpret")
-    for s in (a, b):
-        for sid in range(2):
-            s.open_stream(sid)
-    # alternate loud and silent frames so the gate actually closes
-    for t in range(4):
-        fv = LOUD_FV if t % 2 == 0 else SILENCE_FV
-        o_a = a.step({0: fv, 1: SILENCE_FV})
-        o_b = b.step({0: fv, 1: SILENCE_FV})
-        for sid in (0, 1):
-            np.testing.assert_array_equal(
-                o_a[sid]["probs"], o_b[sid]["probs"]
-            )
-    _assert_gru_identical(a, b)
-    np.testing.assert_array_equal(a.wake_rate, b.wake_rate)
-    np.testing.assert_array_equal(a.sparsity, b.sparsity)

@@ -225,8 +225,7 @@ def shared_params():
     )
 
 
-def _server(norm_stats, params, classifier, theta=0.0, max_streams=4,
-            tick_impl="auto"):
+def _server(norm_stats, params, classifier, theta=0.0, max_streams=4):
     pipe = KWSPipeline(
         KWSPipelineConfig(
             classifier=classifier,
@@ -234,9 +233,7 @@ def _server(norm_stats, params, classifier, theta=0.0, max_streams=4,
         ),
         norm_stats=norm_stats,
     )
-    return StreamingKWSServer(
-        pipe, params, max_streams=max_streams, tick_impl=tick_impl
-    )
+    return StreamingKWSServer(pipe, params, max_streams=max_streams)
 
 
 @pytest.mark.parametrize(
@@ -305,37 +302,6 @@ def test_theta_gt0_cross_domain_equality(norm_stats, shared_params):
         np.testing.assert_array_equal(od[0]["probs"], oi[0]["probs"])
     np.testing.assert_array_equal(sd.sparsity, si.sparsity)
     assert sd.sparsity[sd.active[0]] < 1.0
-
-
-@pytest.mark.parametrize(
-    "delta_key,base_key", [("delta", "qat"), ("delta-int", "integer")]
-)
-def test_server_theta0_bit_identical_fused_tick(
-    norm_stats, shared_params, delta_key, base_key
-):
-    """The megakernel tick (gather-compacted Δ·W, interpret tier) keeps
-    the θ=0 telescoping guarantee: fused-interpret delta == xla dense
-    base, cross-backend AND cross-implementation."""
-    sb = _server(norm_stats, shared_params, base_key, tick_impl="xla")
-    sd = _server(
-        norm_stats, shared_params, delta_key, tick_impl="fused-interpret"
-    )
-    for s in (sb, sd):
-        for sid in range(3):
-            s.open_stream(sid)
-    hop = sb.pipeline.chunk_samples
-    rng = np.random.default_rng(8)
-    for t in range(3):
-        slab = rng.standard_normal((4, hop)).astype(np.float32) * 0.05
-        mask = np.zeros(4, bool)
-        mask[:3] = True
-        mask[t % 3] = False
-        s_a, t_a = sb.step_batch(slab, mask)
-        s_b, t_b = sd.step_batch(slab, mask)
-        np.testing.assert_array_equal(s_a, s_b)
-        np.testing.assert_array_equal(t_a, t_b)
-    for hb, std in zip(sb.state.gru, sd.state.gru):
-        np.testing.assert_array_equal(np.asarray(hb), np.asarray(std["h"]))
 
 
 # --------------------------------------------------------------------------

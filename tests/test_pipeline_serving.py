@@ -17,6 +17,7 @@ import pytest
 from repro.core.fex import fit_norm_stats
 from repro.core import quant
 from repro.core.frontend import hardware_state
+from repro.core.gru_delta import DeltaConfig
 from repro.core.pipeline import KWSPipeline, KWSPipelineConfig
 from repro.core.tdfex import TDFExConfig
 from repro.kernels.tdc import tdc_counts
@@ -25,14 +26,20 @@ from repro.serving.serve_loop import ServerState, StreamingKWSServer
 from _hypothesis_compat import given, settings, st
 
 
-def _pipeline_with_stats(audio):
+CLASSIFIERS = ("float", "qat", "integer", "delta", "delta-int")
+
+
+def _pipeline_with_stats(audio, classifier="float"):
     # bootstrap pass (no normalizer) records FV_Raw to fit mu/sigma,
     # mirroring the chip's recording flow (Section III-F)
     boot = KWSPipeline(KWSPipelineConfig(use_norm=False))
     _, fv_raw = boot.features(audio)
     fv_log = quant.log_compress_lut(fv_raw, 12, 10)
     stats = fit_norm_stats(fv_log)
-    return KWSPipeline(KWSPipelineConfig(), norm_stats=stats)
+    # the ΔGRU pair at a real θ, so the ticks skip columns
+    cfg = KWSPipelineConfig(classifier=classifier,
+                            delta=DeltaConfig(0.15, 0.15))
+    return KWSPipeline(cfg, norm_stats=stats)
 
 
 def _audio(batch=2, samples=16000, seed=0, scale=0.05):
@@ -42,8 +49,8 @@ def _audio(batch=2, samples=16000, seed=0, scale=0.05):
     )
 
 
-def _server(max_streams=4, seed=0):
-    pipe = _pipeline_with_stats(_audio(seed=seed))
+def _server(max_streams=4, seed=0, classifier="float"):
+    pipe = _pipeline_with_stats(_audio(seed=seed), classifier)
     params = pipe.init_params(jax.random.PRNGKey(seed))
     return pipe, StreamingKWSServer(pipe, params, max_streams=max_streams)
 
@@ -251,10 +258,11 @@ def test_mixed_kind_tick_rejected():
 # pre-batched ticks + scan replay driver
 # --------------------------------------------------------------------------
 
-def test_step_batch_matches_step():
+@pytest.mark.parametrize("classifier", CLASSIFIERS)
+def test_step_batch_matches_step(classifier):
     """The slab ingress path and the dict path are the same tick."""
-    pipe, srv_a = _server(seed=10)
-    _, srv_b = _server(seed=10)
+    pipe, srv_a = _server(seed=10, classifier=classifier)
+    _, srv_b = _server(seed=10, classifier=classifier)
     srv_b.params = srv_a.params
     for s in (srv_a, srv_b):
         s.open_stream(0)
@@ -277,11 +285,12 @@ def test_step_batch_matches_step():
         assert out[sid]["top"] == int(tops[slot])
 
 
-def test_run_replay_matches_step_sequence():
+@pytest.mark.parametrize("classifier", CLASSIFIERS)
+def test_run_replay_matches_step_sequence(classifier):
     """`run` (lax.scan over the fused tick) == the same audio fed
     hop-by-hop through `step`, including ragged stream lengths."""
-    pipe, srv_live = _server(seed=11)
-    _, srv_scan = _server(seed=11)
+    pipe, srv_live = _server(seed=11, classifier=classifier)
+    _, srv_scan = _server(seed=11, classifier=classifier)
     srv_scan.params = srv_live.params
     hop = pipe.chunk_samples
     rng = np.random.default_rng(11)
@@ -312,9 +321,10 @@ def test_run_replay_matches_step_sequence():
     )
 
 
-def test_run_batch_fv_matches_live_fv_ticks():
-    _, srv_live = _server(seed=12)
-    _, srv_scan = _server(seed=12)
+@pytest.mark.parametrize("classifier", CLASSIFIERS)
+def test_run_batch_fv_matches_live_fv_ticks(classifier):
+    _, srv_live = _server(seed=12, classifier=classifier)
+    _, srv_scan = _server(seed=12, classifier=classifier)
     srv_scan.params = srv_live.params
     n, c = srv_live.max_streams, 16
     rng = np.random.default_rng(12)

@@ -36,7 +36,6 @@ import pytest
 from repro.core import quant
 from repro.core.fex import fit_norm_stats
 from repro.core.pipeline import KWSPipeline, KWSPipelineConfig
-from repro.kernels.tick_fused import tick_reference
 from repro.serving.cascade import CascadeConfig
 from repro.serving.ingress import (
     CoalescedTick,
@@ -46,6 +45,7 @@ from repro.serving.ingress import (
     pack_result,
 )
 from repro.serving.serve_loop import StreamingKWSServer
+from repro.serving.tick import serve_tick
 
 from _hypothesis_compat import given, settings, st
 
@@ -287,19 +287,18 @@ def test_step_batch_is_async_fetched_immediately(qat_server):
 
 
 # --------------------------------------------------------------------------
-# the packed result: tick_reference's own bits, never aliased
+# the packed result: serve_tick's own bits, never aliased
 # --------------------------------------------------------------------------
 
 def _reference_outputs(pipe, srv, ticks, raw):
-    """`tick_reference`'s own (scores, top), no packing, over `ticks`
+    """`serve_tick`'s own (scores, top), no packing, over `ticks`
     from `srv`'s current state (which is read, never donated)."""
-    fn = jax.jit(functools.partial(tick_reference, pipe, raw))
+    fn = jax.jit(functools.partial(serve_tick, pipe, raw))
     st = srv.state
-    state4 = (st.gru, st.carry, st.scores, st.det)
     out = []
     for slab, mask in ticks:
-        state4, scores, top = fn(srv.params, state4, slab, mask,
-                                 srv.frontend_state, srv.smoothing)
+        st, scores, top = fn(srv.params, st, slab, mask,
+                             srv.frontend_state, srv.smoothing)
         out.append((np.asarray(scores), np.asarray(top)))
     return out
 
@@ -308,7 +307,7 @@ def _reference_outputs(pipe, srv, ticks, raw):
     "kind", ["integer", "cascade", "audio", "sharded", "window"]
 )
 def test_packed_result_is_tick_reference_bit_for_bit(norm_stats, kind):
-    """What a handle unpacks on the host is `tick_reference`'s
+    """What a handle unpacks on the host is `serve_tick`'s
     (scores, top), bit for bit — for the integer, cascaded, raw-audio
     and mesh-sharded servers and a `run_batch_async` window — with
     every handle fetched only after the last dispatch and a slot reset

@@ -216,36 +216,6 @@ def test_run_batch_bit_identical(server_pair):
     _assert_states_identical(single, sharded)
 
 
-def test_fused_tick_sharded_bit_identical(backend):
-    """tick_impl="fused-interpret" on the mesh: the megakernel runs
-    once per shard-local slab under `shard_map` (GSPMD cannot partition
-    a pallas_call), and the result still matches the single-device
-    xla-tick server bit for bit — for every backend."""
-    pipe, params = backend
-    single = StreamingKWSServer(
-        pipe, params, max_streams=8, tick_impl="xla"
-    )
-    sharded = StreamingKWSServer(
-        pipe, params, max_streams=8, devices=MESH_DEV,
-        tick_impl="fused-interpret",
-    )
-    assert sharded.tick_dispatch == "interpret"
-    for srv in (single, sharded):
-        for sid in range(8):
-            srv.open_stream(sid)
-    rng = np.random.default_rng(6)
-    hop = pipe.chunk_samples
-    for t in range(3):
-        slab = rng.standard_normal((8, hop)).astype(np.float32) * 0.05
-        mask = np.ones(8, bool)
-        mask[t::3] = False
-        s_a, t_a = single.step_batch(slab, mask)
-        s_b, t_b = sharded.step_batch(slab, mask)
-        np.testing.assert_array_equal(s_a, s_b)
-        np.testing.assert_array_equal(t_a, t_b)
-    _assert_states_identical(single, sharded)
-
-
 def test_dict_step_bit_identical_across_placements(server_pair):
     """`step` with {sid: frame} dicts: the sharded router places the
     same stream ids on different slots/shards than the single-device

@@ -4,13 +4,10 @@ No chip is attached: the TPU compiler builds each program for one
 device of a described ``v5e:2x2`` topology and raises what the chip's
 compiler would raise. Covered at the serving width of 1024 streams:
 
-  * the tick "auto" selects ("xla") for all five classifier backends,
-    on raw audio and on FV_Norm, with the intgemm kernel at the tier
-    "auto" resolves to on TPU ("pallas"), plus the scanned window, and
-    the sharded tick of a ``devices=4`` server on the 2x2 mesh;
-  * the megakernel ("fused-pallas") where it is kept (float on
-    FV_Norm), and the cases `mosaic_unsupported` refuses, which must
-    still fail to compile — a refusal Mosaic has outgrown shows here;
+  * the serving tick for all five classifier backends, on raw audio
+    and on FV_Norm, with the intgemm kernel at the tier "auto" resolves
+    to on TPU ("pallas"), plus the scanned window, the cascaded tick,
+    and the sharded tick of a ``devices=4`` server on the 2x2 mesh;
   * the Pallas kernels reachable on TPU at serving width: intgemm's
     three classifier GEMMs, and tdc, which Mosaic refuses (so "auto"
     resolves it to the reference on TPU).
@@ -38,7 +35,6 @@ from repro.kernels.intgemm import intgemm
 from repro.kernels.intgemm.ops import resolve_intgemm_dispatch
 from repro.kernels.tdc import tdc_counts
 from repro.kernels.tdc.ops import resolve_tdc_dispatch
-from repro.kernels.tick_fused import mosaic_unsupported, tick_fused_pallas
 from repro.serving.cascade import CascadeConfig
 from repro.serving.serve_loop import StreamingKWSServer
 
@@ -129,13 +125,18 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _n_gemm(classifier) -> int:
+    """intgemm kernels one tick lowers to: 2 layers x (W_i, W_h) + FC
+    for the integer backends; the float and qat backends call none."""
+    return 5 if classifier in ("integer", "delta-int") else 0
+
+
 @pytest.mark.parametrize("raw_audio", [True, False], ids=["audio", "fv"])
 @pytest.mark.parametrize("classifier", CLASSIFIERS)
 def test_auto_tick_compiles_for_v5e(one_chip, norm_stats, params,
                                     classifier, raw_audio):
     srv = StreamingKWSServer(_pipe(norm_stats, classifier), params,
                              max_streams=N_STREAMS)
-    assert srv.tick_impl == "xla"
     tick = srv._tick_audio if raw_audio else srv._tick_fv
     run = srv._run_audio if raw_audio else srv._run_fv
     # the tiers "auto" resolves to on a TPU
@@ -144,9 +145,8 @@ def test_auto_tick_compiles_for_v5e(one_chip, norm_stats, params,
         scanned = run.lower(
             *_operands(srv, raw_audio, one_chip, window=WINDOW)
         ).compile()
-    n_gemm = 5 if classifier in ("integer", "delta-int") else 0
-    assert _kernels(compiled) == n_gemm  # 2 layers x (W_i, W_h) + FC
-    assert _kernels(scanned) == n_gemm
+    assert _kernels(compiled) == _n_gemm(classifier)
+    assert _kernels(scanned) == _n_gemm(classifier)
 
 
 def test_tick_ops_carry_stable_names_on_v5e(one_chip, norm_stats, params):
@@ -193,19 +193,21 @@ def test_audio_tick_reads_the_log_rom_without_gather_on_v5e(one_chip,
     assert rom
 
 
-def test_cascaded_auto_tick_compiles_for_v5e(one_chip, norm_stats, params):
+@pytest.mark.parametrize("classifier", CLASSIFIERS)
+def test_cascaded_auto_tick_compiles_for_v5e(one_chip, norm_stats, params,
+                                             classifier):
     srv = StreamingKWSServer(
-        _pipe(norm_stats, "integer", CascadeConfig()), params,
+        _pipe(norm_stats, classifier, CascadeConfig()), params,
         max_streams=N_STREAMS,
     )
     with force_dispatch("pallas"):
         compiled = srv._tick_audio.lower(
             *_operands(srv, True, one_chip)
         ).compile()
-    assert _kernels(compiled) == 5
+    assert _kernels(compiled) == _n_gemm(classifier)
 
 
-@pytest.mark.parametrize("classifier", ["integer", "delta-int"])
+@pytest.mark.parametrize("classifier", CLASSIFIERS)
 def test_sharded_auto_tick_compiles_for_v5e_2x2(topo, one_chip, norm_stats,
                                                 params, classifier):
     """The devices=4 tick: one program over a 4-chip ("stream",) mesh.
@@ -247,13 +249,14 @@ def test_sharded_auto_tick_compiles_for_v5e_2x2(topo, one_chip, norm_stats,
         jax.ShapeDtypeStruct((), jnp.float32, sharding=scalar),
     )
     tick = jax.jit(
-        functools.partial(_tick, pipe, True, tick_impl="xla", mesh=mesh),
+        functools.partial(_tick, pipe, True, mesh=mesh),
         donate_argnums=(1,), in_shardings=in_sh,
         out_shardings=(st_sh, NamedSharding(mesh, P(None, "stream"))),
     )
     with force_dispatch("pallas"):
         text = tick.lower(*operands).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        _n_gemm(classifier))
     assert "all-gather" not in text and "all-reduce" not in text
 
 
@@ -277,43 +280,6 @@ def test_packed_result_is_stream_minor_on_v5e(one_chip, norm_stats, params):
         assert shape[-2:] == (k + 1, N_STREAMS)
         minor = compiled.output_formats[1].layout.major_to_minor[-1]
         assert minor == len(shape) - 1, compiled.output_formats[1]
-
-
-@pytest.mark.parametrize("classifier", ["float"])
-def test_kept_megakernel_compiles_for_v5e(one_chip, norm_stats, params,
-                                          classifier):
-    pipe = _pipe(norm_stats, classifier)
-    assert mosaic_unsupported(pipe, raw_audio=False) is None
-    srv = StreamingKWSServer(pipe, params, max_streams=N_STREAMS,
-                             tick_impl="fused-pallas")
-    compiled = srv._tick_fv.lower(*_operands(srv, False, one_chip)).compile()
-    assert _kernels(compiled) == 1
-
-
-@pytest.mark.parametrize("classifier,cascade,raw_audio", [
-    ("qat", None, True),
-    ("qat", CascadeConfig(), False),
-    ("qat", None, False),
-    ("integer", None, False),
-    ("delta", None, False),
-    ("delta-int", None, False),
-], ids=["qat-audio", "qat-cascade-fv", "qat-fv", "integer-fv", "delta-fv",
-        "delta-int-fv"])
-def test_refused_megakernel_still_fails_on_v5e(one_chip, norm_stats, params,
-                                               classifier, cascade,
-                                               raw_audio):
-    """Each case `mosaic_unsupported` names is one Mosaic cannot lower;
-    compiling the kernel directly, past the refusal, still fails."""
-    pipe = _pipe(norm_stats, classifier, cascade)
-    assert mosaic_unsupported(pipe, raw_audio) is not None
-    srv = StreamingKWSServer(pipe, params, max_streams=N_STREAMS)
-    p, st, x, m, fs, sm = _operands(srv, raw_audio, one_chip)
-    st = (st.gru, st.carry, st.scores, st.det)
-    kernel = jax.jit(functools.partial(
-        tick_fused_pallas, pipe, raw_audio, block_streams=128,
-    ))
-    with pytest.raises(Exception):  # noqa: B017 - Mosaic's error types vary
-        kernel.lower(p, st, x, m, fs, sm).compile()
 
 
 @pytest.mark.parametrize("k,n", [(16, 144), (48, 144), (48, 12)],
